@@ -11,9 +11,10 @@ trainer's ``compute="measured"`` lane and emits
 ``results/bench/perf_iter.json`` with
 
   * per-step wall times (warm-up compile excluded by the engine),
-  * the roofline terms of the compiled SAGE step
+  * on an accelerator, the roofline terms of the compiled SAGE step
     (``repro.launch.roofline.roofline_terms`` over the AOT executable's
-    cost analysis + HLO text) and the achieved fraction of that bound,
+    cost analysis + HLO text, priced at that chip's peaks) and the
+    achieved fraction of that bound (``null`` on CPU),
   * an aggregation microbenchmark — the engine's compiled block-sparse
     path vs a jitted per-edge segment-sum reference at SAGE-layer-like
     many-to-few shapes (min-of-k timing),
@@ -169,29 +170,47 @@ def bench_sage(args) -> dict:
     gpu_mod = float(res_mod.meter.gpu_j)
     energy_delta = abs(gpu_meas - gpu_mod) / max(gpu_mod, 1e-12)
 
-    # roofline of the compiled step: one standalone engine, one step, then
-    # read the AOT executable's cost analysis + HLO text
-    graph, _owner, _traces, mbs = bundle
-    eng = ComputeEngine(graph, cfg)
-    mb = mbs[0][0]
-    eng.step(
-        mb, np.asarray(graph.features[mb.input_nodes], np.float32),
-        key=(0, 0),
-    )
-    exe = next(iter(eng._exec.values()))
-    cost = exe.cost_analysis()
-    if not isinstance(cost, dict):
-        cost = cost[0]
-    terms = rl.roofline_terms(cost, exe.as_text(), 1.0)
-    bound_s = max(
-        terms["compute_s"], terms["memory_s"], terms["collective_s"]
-    )
     mean_step = float(step_s.mean())
+    roofline = None
+    dev = jax.devices()[0]
+    if dev.platform != "cpu":
+        # roofline of the compiled step, priced at the running chip's own
+        # peaks (a CPU wall time is no device metric, so a CPU run records
+        # none): one standalone engine, one step, then the AOT
+        # executable's cost analysis + HLO text
+        graph, _owner, _traces, mbs = bundle
+        eng = ComputeEngine(graph, cfg)
+        mb = mbs[0][0]
+        eng.step(
+            mb, np.asarray(graph.features[mb.input_nodes], np.float32),
+            key=(0, 0),
+        )
+        exe = next(iter(eng._exec.values()))
+        cost = exe.cost_analysis()
+        if not isinstance(cost, dict):
+            cost = cost[0]
+        terms = rl.roofline_terms(cost, exe.as_text(), 1.0,
+                                  device_kind=dev.device_kind)
+        bound_s = max(
+            terms["compute_s"], terms["memory_s"], terms["collective_s"]
+        )
+        roofline = {
+            "compute_s": terms["compute_s"],
+            "memory_s": terms["memory_s"],
+            "collective_s": terms["collective_s"],
+            "dominant": terms["dominant"],
+            "roofline_fraction": round(terms["roofline_fraction"], 4),
+            "bound_s": bound_s,
+            # wall time over the ideal-hardware bound: -> 1 as the step
+            # approaches the chip's roofline
+            "achieved_over_bound": round(mean_step / max(bound_s, 1e-12), 2),
+        }
 
     agg = bench_aggregation()
 
     return {
         "backend": jax.default_backend(),
+        "device_kind": dev.device_kind,
         "agg_impl": rep["agg_impl"],
         "grad_compression": rep["grad_compression"],
         "sync_wire_bytes": rep["sync_wire_bytes"],
@@ -201,17 +220,7 @@ def bench_sage(args) -> dict:
         "min_step_s": round(float(step_s.min()), 6),
         "compile_s": round(float(rep["compile_s"]), 3),
         "parity_max_diff": rep["parity_max_diff"],
-        "roofline": {
-            "compute_s": terms["compute_s"],
-            "memory_s": terms["memory_s"],
-            "collective_s": terms["collective_s"],
-            "dominant": terms["dominant"],
-            "roofline_fraction": round(terms["roofline_fraction"], 4),
-            "bound_s": bound_s,
-            # wall time over the ideal-hardware bound: >> 1 on CPU, -> 1
-            # as the step approaches the v5e roofline
-            "achieved_over_bound": round(mean_step / max(bound_s, 1e-12), 2),
-        },
+        "roofline": roofline,
         "energy": {
             "measured_gpu_j": gpu_meas,
             "modeled_gpu_j_calibrated": gpu_mod,
@@ -260,6 +269,9 @@ def sage_main(argv) -> int:
                    help="output path (default results/bench/perf_iter.json)")
     args = p.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     rec = bench_sage(args)
     os.makedirs(os.path.dirname(args.json), exist_ok=True)
     with open(args.json, "w") as f:

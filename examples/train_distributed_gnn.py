@@ -12,6 +12,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train import checkpoint as ckpt
 from repro.train import gnn_trainer as gt
 from repro.train import policy as pol
@@ -29,6 +30,7 @@ def main():
              "(measured rebuild overlap) instead of the analytic model",
     )
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = gt.RunConfig(
         method="greendygnn", dataset=args.dataset, batch_size=2000,

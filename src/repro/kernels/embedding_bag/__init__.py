@@ -1,1 +1,5 @@
-from repro.kernels.embedding_bag.ops import embedding_bag_pallas  # noqa: F401
+from repro.kernels.embedding_bag.ops import (  # noqa: F401
+    embedding_bag_pallas,
+    gather_layout,
+    gather_rows,
+)

@@ -1,61 +1,68 @@
-"""EmbeddingBag Pallas kernel: scalar-prefetch-driven row gather + bag sum.
+"""Row-gather Pallas kernel behind EmbeddingBag and the device tier.
 
-JAX has no native EmbeddingBag; on TPU the gather is expressed by letting the
-*prefetched index array drive the BlockSpec index map*: grid step i pulls
-table row idx[i] HBM->VMEM, and accumulates into the output row seg[i]
-(segments must be sorted so each bag's grid steps are consecutive — the
-revisit-consecutive output pattern again, no atomics needed).
+JAX has no native EmbeddingBag. On TPU the lookup rows are gathered by
+DMA: the table stays in HBM (``memory_space=pl.ANY``), the scalar-
+prefetched index array names the rows, and each grid step copies
+``ROWS_PER_STEP`` of them into a VMEM buffer that becomes one
+(ROWS_PER_STEP, D) output block. A block of a single row would break the
+TPU's (8, 128) tiling, so rows travel in groups of eight.
 
-Rows are (1, D) tiles; D is the lane dimension (pad to x128 for the VPU).
+The table is laid out (R, 1, D) with D a multiple of 128: one row per
+leading index, so a DMA of one row is a whole tile and needs no sublane
+offset. Bag reduction and per-lookup weights happen outside the kernel
+(``ops.embedding_bag_pallas``); the gather itself is exact, so one index
+per bag at unit weight is bit-equal to ``table[idx]``.
 """
 from __future__ import annotations
 
 from functools import partial
 
 import jax
-import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _bag_kernel(idx_ref, seg_ref, row_ref, w_ref, o_ref):
-    i = pl.program_id(0)
-    n = pl.num_programs(0)
-    seg = seg_ref[i]
-    prev = seg_ref[jnp.maximum(i - 1, 0)]
-    row = row_ref[...] * w_ref[0, 0]
-
-    @pl.when((i == 0) | (prev != seg))
-    def _first():
-        o_ref[...] = row
-
-    @pl.when(~((i == 0) | (prev != seg)))
-    def _accum():
-        o_ref[...] += row
+ROWS_PER_STEP = 8   # one (8, 128) sublane tile of output rows per grid step
 
 
-@partial(jax.jit, static_argnames=("n_bags", "interpret"))
-def embedding_bag_kernel(
-    indices: jax.Array,   # (L,) int32, bag-sorted
-    segments: jax.Array,  # (L,) int32, sorted ascending
-    table: jax.Array,     # (R, D)
-    weights: jax.Array,   # (L, 1) per-lookup scale
-    n_bags: int,
-    interpret: bool = True,
+def _gather_kernel(idx_ref, table_ref, o_ref, buf, sems):
+    base = pl.program_id(0) * ROWS_PER_STEP
+    copies = [
+        pltpu.make_async_copy(
+            table_ref.at[pl.ds(idx_ref[base + k], 1)],
+            buf.at[pl.ds(k, 1)],
+            sems.at[k],
+        )
+        for k in range(ROWS_PER_STEP)
+    ]
+    for c in copies:
+        c.start()
+    for c in copies:
+        c.wait()
+    o_ref[...] = buf[...].reshape(o_ref.shape)
+
+
+@partial(jax.jit, static_argnames=("interpret",))
+def gather_rows_kernel(
+    indices: jax.Array,   # (L,) int32, L % ROWS_PER_STEP == 0
+    table: jax.Array,     # (R, 1, D), D % 128 == 0
+    interpret: bool = False,
 ):
+    """``table[indices, 0]`` as an (L, D) array."""
     l = indices.shape[0]
-    d = table.shape[1]
+    _, one, d = table.shape
+    assert one == 1 and l % ROWS_PER_STEP == 0
     return pl.pallas_call(
-        _bag_kernel,
+        _gather_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(l,),
-            in_specs=[
-                pl.BlockSpec((1, d), lambda i, idx, seg: (idx[i], 0)),
-                pl.BlockSpec((1, 1), lambda i, idx, seg: (i, 0)),
+            num_scalar_prefetch=1,
+            grid=(l // ROWS_PER_STEP,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((ROWS_PER_STEP, d), lambda g, idx: (g, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((ROWS_PER_STEP, 1, d), table.dtype),
+                pltpu.SemaphoreType.DMA((ROWS_PER_STEP,)),
             ],
-            out_specs=pl.BlockSpec((1, d), lambda i, idx, seg: (seg[i], 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((n_bags, d), table.dtype),
+        out_shape=jax.ShapeDtypeStruct((l, d), table.dtype),
         interpret=interpret,
-    )(indices, segments, table, weights)
+    )(indices, table)

@@ -1,10 +1,40 @@
-"""Public EmbeddingBag wrapper: sorting, empty-bag zeroing, weight defaults."""
+"""Public EmbeddingBag ops: table layout, padding, bag reduction."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.embedding_bag.kernel import embedding_bag_kernel
+from repro.kernels.embedding_bag.kernel import ROWS_PER_STEP, gather_rows_kernel
+from repro.kernels.segment_mm.kernel import default_interpret
+
+LANES = 128
+
+
+def gather_layout(table) -> jax.Array:
+    """(R, D) rows -> the kernel's (R, 1, D_pad) layout, D_pad % 128 == 0.
+
+    Build the device table in this layout once; ``gather_rows`` then
+    reads it in place.
+    """
+    table = jnp.asarray(table)
+    d = table.shape[1]
+    table = jnp.pad(table, ((0, 0), (0, -(-d // LANES) * LANES - d)))
+    return table[:, None, :]
+
+
+def gather_rows(table: jax.Array, indices, n_feat: int,
+                interpret: bool | None = None) -> jax.Array:
+    """Exact row gather ``table[indices, 0, :n_feat]`` from a table in
+    :func:`gather_layout`; the index count is padded to the kernel's step."""
+    if interpret is None:
+        interpret = default_interpret()
+    indices = jnp.asarray(indices, jnp.int32)
+    n = indices.shape[0]
+    n_pad = -(-n // ROWS_PER_STEP) * ROWS_PER_STEP
+    if n_pad != n:
+        indices = jnp.pad(indices, (0, n_pad - n))
+    out = gather_rows_kernel(indices, table, interpret=bool(interpret))
+    return out[:n, :n_feat]
 
 
 def embedding_bag_pallas(
@@ -13,24 +43,14 @@ def embedding_bag_pallas(
     segment_ids: jax.Array,
     n_bags: int,
     weights: jax.Array | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
-    """Sum-mode EmbeddingBag via the Pallas kernel.
-
-    Handles unsorted segments (stable sort) and empty bags (zeroed after the
-    kernel, since untouched output rows are undefined).
-    """
-    indices = jnp.asarray(indices, jnp.int32)
-    segment_ids = jnp.asarray(segment_ids, jnp.int32)
-    if weights is None:
-        weights = jnp.ones((indices.shape[0],), table.dtype)
-    order = jnp.argsort(segment_ids, stable=True)
-    idx_s = indices[order]
-    seg_s = segment_ids[order]
-    w_s = weights[order][:, None].astype(table.dtype)
-    out = embedding_bag_kernel(idx_s, seg_s, table, w_s, n_bags,
-                               interpret=interpret)
-    counts = jax.ops.segment_sum(
-        jnp.ones_like(seg_s, jnp.int32), seg_s, num_segments=n_bags
+    """Sum-mode EmbeddingBag: Pallas row gather, then a weighted segment
+    sum. Segments may be unsorted; empty bags come out zero."""
+    d = table.shape[1]
+    rows = gather_rows(gather_layout(table), indices, d, interpret=interpret)
+    if weights is not None:
+        rows = rows * jnp.asarray(weights, table.dtype)[:, None]
+    return jax.ops.segment_sum(
+        rows, jnp.asarray(segment_ids, jnp.int32), num_segments=n_bags
     )
-    return jnp.where(counts[:, None] > 0, out, 0.0)

@@ -9,24 +9,16 @@ from __future__ import annotations
 import jax
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """``axis_types`` only exists on newer jax; omit it elsewhere (the
-    default is Auto on every version that lacks the enum)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; multi_pod adds the 2-pod axis (512)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return make_mesh_from_shape(shape, axes)
 
 
 def make_mesh_from_shape(shape: tuple, axes: tuple):
     """Arbitrary mesh (elastic restarts: e.g. (1, 16, 16) after pod loss)."""
     return jax.make_mesh(
-        tuple(shape), tuple(axes), **_axis_type_kwargs(len(axes))
+        tuple(shape), tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
     )

@@ -1,6 +1,7 @@
 """Roofline-term extraction from compiled dry-run artifacts.
 
-Three terms per (arch x shape x mesh), TPU v5e constants:
+Three terms per (arch x shape x mesh), priced at one chip's published
+peaks (``PEAKS``, keyed by ``jax.Device.device_kind``):
   compute    = HLO_FLOPs_per_device / peak_FLOPs          [s]
   memory     = HLO_bytes_per_device / HBM_bw              [s]
   collective = collective_bytes_per_device / link_bw      [s]
@@ -12,11 +13,41 @@ all-to-all / collective-permute ops (also per-device shapes).
 """
 from __future__ import annotations
 
+import dataclasses
 import re
 
-PEAK_FLOPS = 197e12        # bf16 per chip
-HBM_BW = 819e9             # bytes/s per chip
-LINK_BW = 50e9             # bytes/s per ICI link
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    flops: float      # bf16 FLOP/s per chip
+    hbm_bw: float     # HBM bytes/s per chip
+    link_bw: float    # bytes/s per ICI link
+    source: str
+
+
+# Published per-chip peaks, keyed by the ``device_kind`` JAX reports.
+PEAKS = {
+    # 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s of interconnect over
+    # four ICI links (50 GB/s each)
+    "TPU v5 lite": DevicePeaks(
+        flops=197e12, hbm_bw=819e9, link_bw=50e9,
+        source='Google Cloud documentation, "TPU v5e"',
+    ),
+}
+# the chip the dry-run cells are lowered for (launch/dryrun.py)
+TARGET_KIND = "TPU v5 lite"
+
+
+def device_peaks(kind: str) -> DevicePeaks:
+    """Peaks of one chip of ``kind``; a kind missing from ``PEAKS`` is an
+    error, never a default."""
+    try:
+        return PEAKS[kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {kind!r}; add them to "
+            f"repro.launch.roofline.PEAKS with their source"
+        ) from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -80,14 +111,16 @@ def loop_factor(arch_id: str, shape_name: str) -> float:
     return 1.0
 
 
-def roofline_terms(cost: dict, hlo_text: str, factor: float = 1.0) -> dict:
+def roofline_terms(cost: dict, hlo_text: str, factor: float = 1.0,
+                   device_kind: str = TARGET_KIND) -> dict:
+    peaks = device_peaks(device_kind)
     flops = float(cost.get("flops", 0.0) or 0.0) * factor
     bytes_accessed = float(cost.get("bytes accessed", 0.0) or 0.0) * factor
     coll = {k: v * factor for k, v in collective_bytes(hlo_text).items()}
     coll_total = sum(coll.values())
-    compute_s = flops / PEAK_FLOPS
-    memory_s = bytes_accessed / HBM_BW
-    collective_s = coll_total / LINK_BW
+    compute_s = flops / peaks.flops
+    memory_s = bytes_accessed / peaks.hbm_bw
+    collective_s = coll_total / peaks.link_bw
     terms = {
         "loop_factor": factor,
         "flops_per_device": flops,

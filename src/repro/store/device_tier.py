@@ -1,38 +1,38 @@
 """Device tier: capacity-bounded payload buffer over the hot-node cache.
 
 The ``DoubleBufferedCache`` tracks hot node *ids*; this tier holds the
-actual feature payload rows for the active buffer (what the GPU would keep
-in device memory) and serves the hit path through the
-``kernels.embedding_bag`` Pallas gather — one index per bag with unit
-weight is an exact row gather, so the kernel output is bit-comparable to a
-plain ``table[idx]`` (asserted by the parity tests).
+actual feature payload rows for the active buffer (what the accelerator
+keeps in device memory) and serves the hit path through the
+``kernels.embedding_bag`` Pallas row gather — an exact gather, so the
+kernel output is bit-equal to a plain ``table[idx]`` (asserted by the
+parity tests).
 
 The gather pads the request length to the next power of two so the jitted
-kernel (static ``n_bags``) compiles once per size bucket instead of once
-per distinct batch length; the payload table itself is zero-padded to the
-cache capacity so its shape is static for the whole run. ``interpret=True``
-is the CPU fallback — flip it off on a real TPU backend.
+kernel compiles once per size bucket instead of once per distinct batch
+length; the payload table itself is zero-padded to the cache capacity (and
+its width to the kernel's 128-lane layout) so its shape is static for the
+whole run. The kernel compiles on TPU and runs in the Pallas interpreter
+only on CPU (``segment_mm.default_interpret``).
 """
 from __future__ import annotations
 
 import numpy as np
 
 from repro.core.windowed_cache import DoubleBufferedCache, RebuildPlan
-from repro.kernels.embedding_bag import embedding_bag_pallas
+from repro.kernels.embedding_bag import gather_layout, gather_rows
 
 
 class DevicePayloadTier:
     """Payload rows for the cache's active buffer + kernel-served hit path."""
 
     def __init__(self, cache: DoubleBufferedCache, n_feat: int,
-                 dtype=np.float32, interpret: bool = True):
+                 dtype=np.float32):
         self.cache = cache
         self.n_feat = int(n_feat)
         self.dtype = np.dtype(dtype)
-        self.interpret = bool(interpret)
         self.capacity = int(cache.capacity)
         self._payload = np.zeros((0, self.n_feat), self.dtype)
-        self._table = None          # jnp zero-padded (capacity, n_feat) view
+        self._table = None          # device (capacity, 1, D_pad) gather layout
         self.n_loads = 0
         self.rows_gathered = 0
 
@@ -76,21 +76,13 @@ class DevicePayloadTier:
         if n == 0 or len(self._payload) == 0:
             return np.zeros((0, self.n_feat), self.dtype)
         if self._table is None:
-            import jax.numpy as jnp
-
             padded = np.zeros((self.capacity, self.n_feat), self.dtype)
             padded[: len(self._payload)] = self._payload
-            self._table = jnp.asarray(padded)
+            self._table = gather_layout(padded)
         bucket = 1 << (n - 1).bit_length()
-        idx = np.zeros(bucket, np.int32)
+        idx = np.zeros(bucket, np.int32)  # pad lookups read slot 0, cut below
         idx[:n] = np.asarray(slot_idx, np.int32)
-        seg = np.arange(bucket, dtype=np.int32)
-        w = np.zeros(bucket, np.float32)
-        w[:n] = 1.0  # pad bags carry weight 0 -> exact gather after slicing
-        out = embedding_bag_pallas(
-            self._table, idx, seg, n_bags=bucket, weights=w,
-            interpret=self.interpret,
-        )
+        out = gather_rows(self._table, idx, self.n_feat)
         self.rows_gathered += n
         return np.asarray(out)[:n].astype(self.dtype)
 

@@ -13,18 +13,21 @@ resolved, with neighborhood aggregation dispatched through the
     so the measured numbers are real compiled-step times everywhere.
 
 The edge-list -> block conversion is numpy preprocessing, cached per
-mini-batch in a bounded LRU so the steps inside a rebuild window reuse
-their prepared batches (the conversion is amortized exactly like the
-cache rebuild itself; see DESIGN.md "Measured vs modeled, part 3").
+mini-batch key in an LRU bounded by device bytes, so a batch stepped again
+skips the conversion and upload while device memory stays bounded: at
+reddit width one batch's layer-0 tiles alone take ~4.3 GB, so such a batch
+is uploaded for its step and dropped after it.
 Dynamic block/src/dst counts are bucketed to powers of two so the jitted
 step compiles once per size bucket; compilation happens ahead-of-time
 (``.lower().compile()``) and is excluded from the measured step time.
 
 The block path is parity-asserted against the ``models/gnn/common``
 scatter reference (``check_parity``, run automatically on the first
-step). Gradient sync flows through ``grad_compression`` with error
-feedback; ``sync_wire_bytes`` is what the cluster driver feeds into
-``ring_collective_cost`` in place of the uncompressed payload.
+step). On an accelerator the engine prices its steps at the chip's
+published peaks (``launch.roofline.PEAKS``); an accelerator missing from
+that table is an error. Gradient sync flows through ``grad_compression``
+with error feedback; ``sync_wire_bytes`` is what the cluster driver feeds
+into ``ring_collective_cost`` in place of the uncompressed payload.
 """
 from __future__ import annotations
 
@@ -85,11 +88,12 @@ class ComputeEngine:
 
     def __init__(self, graph, cfg, agg_impl: str = "auto",
                  clock: Callable[[], float] | None = None,
-                 cache_size: int = 16, tile: int = 128):
+                 cache_bytes: int = 1 << 30, tile: int = 128):
         import jax
 
         from repro import optim
         from repro.kernels.segment_mm import default_interpret
+        from repro.launch.roofline import device_peaks
 
         scheme = getattr(cfg, "grad_compression", "none")
         if scheme not in _SCHEMES:
@@ -103,6 +107,14 @@ class ComputeEngine:
 
         from repro.models.gnn import sage
 
+        device = jax.devices()[0]
+        self.device_kind = device.device_kind
+        # per-chip peaks for the roofline terms; None on CPU, where a wall
+        # time prices nothing
+        self.peaks = (
+            None if device.platform == "cpu"
+            else device_peaks(device.device_kind)
+        )
         self.graph = graph
         self.mcfg = sage_config(graph)
         self.tile = int(tile)
@@ -125,7 +137,8 @@ class ComputeEngine:
         self._fwd_jit = jax.jit(self._forward)
         self._exec: dict = {}            # shape signature -> AOT executable
         self._prep: OrderedDict = OrderedDict()   # mb id -> PreparedBatch
-        self._cache_size = int(cache_size)
+        self._prep_nbytes: dict = {}              # mb id -> device bytes
+        self._cache_bytes = int(cache_bytes)
 
         self.losses: list[float] = []
         self.step_s: list[float] = []
@@ -139,18 +152,24 @@ class ComputeEngine:
     def prepare(self, mb, key=None):
         """Block-sparse conversion + pow2 bucketing for one mini-batch.
 
-        Returns ``(layers, x_rows, n_edges, sig)``; cached per ``key``
-        (the worker passes ``(epoch, step)``) in a bounded LRU so repeat
-        visits inside a rebuild window skip the numpy conversion.
+        Returns ``(layers, x_rows, n_edges)``; cached per ``key`` (the
+        worker passes ``(epoch, step)``) in an LRU holding at most
+        ``cache_bytes`` of device arrays. A batch larger than that is
+        never cached.
         """
+        import jax
+
         if key is not None and key in self._prep:
             self._prep.move_to_end(key)
             return self._prep[key]
         prep = self._prepare(mb)
-        if key is not None:
+        nbytes = sum(a.nbytes for a in jax.tree.leaves(prep[0]))
+        if key is not None and nbytes <= self._cache_bytes:
             self._prep[key] = prep
-            while len(self._prep) > self._cache_size:
-                self._prep.popitem(last=False)
+            self._prep_nbytes[key] = nbytes
+            while sum(self._prep_nbytes.values()) > self._cache_bytes:
+                old, _ = self._prep.popitem(last=False)
+                del self._prep_nbytes[old]
         return prep
 
     def _prepare(self, mb):
@@ -280,8 +299,8 @@ class ComputeEngine:
 
         ``x_in`` are the resolved feature rows for ``mb.input_nodes``.
         Returns the measured wall seconds of the compiled step (AOT
-        compilation on a new shape bucket is excluded and accounted in
-        ``compile_s``). Loss/edge-count/timing streams accumulate on the
+        compilation on a new shape bucket and the upload of the inputs
+        are excluded; compilation is accounted in ``compile_s``). Loss/edge-count/timing streams accumulate on the
         engine for calibration and reporting.
         """
         import jax
@@ -301,6 +320,9 @@ class ComputeEngine:
             self._exec[sig] = self._jit.lower(*args).compile()
             self.compile_s += self.clock() - t0
             self.n_compiles += 1
+        # host->device copies are asynchronous: finish them before the
+        # timer, or a reddit-width batch's ~4.4 GB upload lands in step_s
+        jax.block_until_ready(args)
         t0 = self.clock()
         out = self._exec[sig](*args)
         jax.block_until_ready(out)
@@ -319,8 +341,12 @@ class ComputeEngine:
         The reference is ``sage.apply_blocks`` (per-edge gather +
         ``common.scatter_sum``/mean) on the UNPADDED blocks; the block
         path must agree on every valid dst row within float-accumulation
-        tolerance (summation order differs between the two).
+        tolerance (summation order differs between the two). Both sides
+        run at full float32 matmul precision, so the check sees the
+        block-sparse aggregation (tiles, padding, kernel) and not the
+        backend's default matmul precision (one bf16 pass on TPU).
         """
+        import jax
         import jax.numpy as jnp
 
         from repro.models.gnn import sage
@@ -331,7 +357,6 @@ class ComputeEngine:
         else:
             layers, x_rows = _prep
         x_pad = self.pad_input(np.asarray(x_in, np.float32), x_rows)
-        got = self._fwd_jit(self.params, jnp.asarray(x_pad), layers)
         ref_blocks = [
             {
                 "edge_src": jnp.asarray(b.edge_src),
@@ -341,10 +366,12 @@ class ComputeEngine:
             }
             for b in mb.blocks
         ]
-        ref = sage.apply_blocks(
-            self.params, self.mcfg,
-            jnp.asarray(np.asarray(x_in, np.float32)), ref_blocks,
-        )
+        with jax.default_matmul_precision("highest"):
+            got = self._fwd_jit(self.params, jnp.asarray(x_pad), layers)
+            ref = sage.apply_blocks(
+                self.params, self.mcfg,
+                jnp.asarray(np.asarray(x_in, np.float32)), ref_blocks,
+            )
         n = ref.shape[0]
         valid = np.asarray(mb.blocks[-1].dst_mask, bool)
         diff = np.abs(np.asarray(got)[:n] - np.asarray(ref))[valid]
@@ -380,6 +407,7 @@ class ComputeEngine:
             "compile_s": self.compile_s,
             "n_compiles": self.n_compiles,
             "agg_impl": self.agg_impl,
+            "device_kind": self.device_kind,
             "grad_compression": self.scheme,
             "sync_wire_bytes": self.sync_wire_bytes,
             "parity_max_diff": self.parity_max_diff,

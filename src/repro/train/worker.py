@@ -414,24 +414,26 @@ class TrainerWorker:
         t0 = self.meter.wall_s
         gstep = epoch * self.cfg.steps_per_epoch + step
         if self.engine is not None and self.engine.step_edges:
-            # roofline terms for the measured SAGE step: per-edge flop/byte
-            # estimate priced at the chip's peak rates (order-of-magnitude
-            # attribution, not a fitted law — calibration.calibrate_compute
-            # owns the fitted one)
-            from repro.launch.roofline import HBM_BW, PEAK_FLOPS
-
+            # per-edge flop/byte estimate of the measured SAGE step; on an
+            # accelerator also priced at that chip's peak rates
+            # (order-of-magnitude attribution, not a fitted law —
+            # calibration.calibrate_compute owns the fitted one)
             n_edges = int(self.engine.step_edges[-1])
             width = float(self.engine.mcfg.d_in + self.engine.mcfg.d_hidden)
             flops = 2.0 * n_edges * width
             nbyte = 4.0 * n_edges * width
-            comp_s, mem_s = flops / PEAK_FLOPS, nbyte / HBM_BW
+            args = {"n_edges": n_edges, "flops_est": flops,
+                    "bytes_est": nbyte}
+            peaks = self.engine.peaks
+            if peaks is not None:
+                comp_s, mem_s = flops / peaks.flops, nbyte / peaks.hbm_bw
+                args.update(
+                    roof_compute_s=comp_s, roof_memory_s=mem_s,
+                    bound="memory" if mem_s >= comp_s else "compute",
+                )
             self.tracer.span(
                 "compute", "measured", t0, t0 + t_compute, step=gstep,
-                epoch=epoch,
-                args={"n_edges": n_edges, "flops_est": flops,
-                      "bytes_est": nbyte, "roof_compute_s": comp_s,
-                      "roof_memory_s": mem_s,
-                      "bound": "memory" if mem_s >= comp_s else "compute"},
+                epoch=epoch, args=args,
             )
         self.tracer.charge_step(
             t0,

@@ -103,6 +103,67 @@ class TestBlockAggregation:
 
 
 # ---------------------------------------------------------------------------
+# device plumbing: peak table, prepared-batch bound, compile cache
+# ---------------------------------------------------------------------------
+
+class TestDevicePlumbing:
+    def test_peak_table_known_and_unknown_kinds(self):
+        from repro.launch.roofline import device_peaks
+
+        v5e = device_peaks("TPU v5 lite")
+        assert (v5e.flops, v5e.hbm_bw) == (197e12, 819e9)
+        assert "TPU v5e" in v5e.source
+        with pytest.raises(ValueError, match="no published peaks"):
+            device_peaks("TPU v99")
+
+    def test_cpu_engine_prices_nothing(self):
+        from repro.train import gnn_trainer as gt
+        from repro.train.compute import ComputeEngine
+
+        cfg = gt.RunConfig(**_PIN_CFG)
+        graph = gt.datasets.materialize(cfg.dataset, seed=0)
+        eng = ComputeEngine(graph, cfg)
+        assert eng.peaks is None and eng.agg_impl == "xla"
+
+    @pytest.mark.parametrize("budget_batches", [0, 1, 3])
+    def test_prepared_batches_bounded_by_bytes(self, budget_batches):
+        from repro.train import gnn_trainer as gt
+        from repro.train.compute import ComputeEngine
+
+        cfg = gt.RunConfig(**dict(_PIN_CFG, n_epochs=1, steps_per_epoch=4))
+        graph, _owner, _traces, mbs = gt.build_trace(cfg)
+        probe = ComputeEngine(graph, cfg)
+        sizes = [
+            sum(a.nbytes for a in jax.tree.leaves(probe.prepare(mb)[0]))
+            for mb in mbs[0]
+        ]
+        cap = max(sizes) * budget_batches
+        eng = ComputeEngine(graph, cfg, cache_bytes=cap)
+        for s, mb in enumerate(mbs[0]):
+            eng.prepare(mb, key=(0, s))
+            assert sum(eng._prep_nbytes.values()) <= cap
+        assert len(eng._prep) <= budget_batches
+        if budget_batches:
+            # the newest batch is cached and served again without rework
+            last = eng._prep[(0, 3)]
+            assert eng.prepare(mbs[0][3], key=(0, 3)) is last
+
+    def test_compile_cache_env_wins_else_fixed_path(self, monkeypatch):
+        from repro.launch import compile_cache as cc
+
+        updates = []
+        monkeypatch.setattr(jax.config, "update",
+                            lambda k, v: updates.append((k, v)))
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert cc.enable_compile_cache() == "/elsewhere"
+        assert updates == []
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert cc.enable_compile_cache() == cc.CACHE_DIR
+        assert updates == [("jax_compilation_cache_dir", cc.CACHE_DIR)]
+        assert cc.CACHE_DIR.endswith(".jax_cache")
+
+
+# ---------------------------------------------------------------------------
 # gradient compression
 # ---------------------------------------------------------------------------
 

@@ -4,12 +4,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.embedding_bag import embedding_bag_pallas
+from repro.kernels.embedding_bag import (
+    embedding_bag_pallas, gather_layout, gather_rows,
+)
 from repro.kernels.embedding_bag.ref import embedding_bag_ref
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.flash_attention.kernel import flash_attention_kernel
 from repro.kernels.flash_attention.ref import attention_ref
-from repro.kernels.segment_mm import segment_mm, to_block_sparse
+from repro.kernels.segment_mm import (
+    block_spmm_xla, segment_mm, to_block_sparse,
+)
+from repro.kernels.segment_mm.kernel import block_spmm_kernel
 from repro.kernels.segment_mm.ref import spmm_ref
 
 
@@ -55,6 +60,39 @@ class TestSegmentMM:
         want = spmm_ref(jnp.asarray(src), jnp.asarray(dst), x, 80, jnp.asarray(w))
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-3, rtol=1e-3)
+
+    @pytest.mark.parametrize("n_src,src_hi,n_dst,n_edges,f,t", [
+        (300, 300, 260, 2000, 64, 32),
+        (64, 64, 700, 300, 32, 32),      # many empty dst blocks
+        (1000, 1000, 50, 4000, 32, 64),  # many-to-few
+        (512, 100, 96, 200, 32, 32),     # edges reach only low src blocks
+    ])
+    def test_vjp_matches_xla_grad(self, n_src, src_hi, n_dst, n_edges, f,
+                                  t):
+        """The kernel's custom VJP (same kernel, source-sorted, transposed
+        tiles) gives the XLA block path's gradient; source blocks no edge
+        reaches get exactly zero."""
+        rng = np.random.default_rng(n_src + n_edges)
+        src = rng.integers(0, src_hi, n_edges)
+        dst = rng.integers(0, n_dst, n_edges)
+        rows, cols, blocks, ndb, n_src_pad = to_block_sparse(
+            src, dst, n_dst, n_src, t, t
+        )
+        r, c, b = map(jnp.asarray, (rows, cols, blocks))
+        x = jnp.asarray(rng.standard_normal((n_src_pad, f)), jnp.float32)
+        g = jnp.asarray(rng.standard_normal((ndb * t, f)), jnp.float32)
+
+        def grad(spmm):
+            return jax.grad(lambda x: jnp.sum(spmm(x) * g))(x)
+
+        got = grad(lambda x: block_spmm_kernel(r, c, b, x, ndb, tn=t, tm=t,
+                                               tf=32))
+        want = grad(lambda x: block_spmm_xla(r, c, b, x, ndb, tn=t, tm=t))
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-5, rtol=1e-6)
+        unreached = np.setdiff1d(np.arange(n_src_pad // t), cols)
+        for blk in unreached:
+            assert not np.asarray(got)[blk * t:(blk + 1) * t].any()
 
     def test_block_sparse_format_complete(self):
         """Every dst block covered; blocks reproduce the adjacency."""
@@ -145,6 +183,20 @@ class TestEmbeddingBag:
         got = embedding_bag_pallas(table, idx, seg, 3, weights=w)
         want = embedding_bag_ref(table, idx, seg, 3, weights=w)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+
+    @pytest.mark.parametrize("rows,dim,lookups", [
+        (50, 602, 37),    # reddit width: lanes padded 602 -> 640
+        (81, 128, 64),
+        (9, 5, 3),        # fewer lookups than one kernel step
+    ])
+    def test_gather_layout_bit_equal(self, rows, dim, lookups):
+        """The (R, 1, D_pad) DMA-gather layout returns table[idx] exactly."""
+        rng = np.random.default_rng(rows * dim)
+        table = rng.standard_normal((rows, dim)).astype(np.float32)
+        idx = rng.integers(0, rows, lookups)
+        got = np.asarray(gather_rows(gather_layout(table), idx, dim))
+        assert got.shape == (lookups, dim)
+        assert np.array_equal(got, table[idx])
 
     def test_empty_bags_zeroed(self):
         table = jnp.ones((5, 4))
