@@ -31,6 +31,7 @@ into ``ring_collective_cost`` in place of the uncompressed payload.
 """
 from __future__ import annotations
 
+import gc as python_gc
 import time
 from collections import OrderedDict
 from typing import Callable
@@ -300,8 +301,9 @@ class ComputeEngine:
         ``x_in`` are the resolved feature rows for ``mb.input_nodes``.
         Returns the measured wall seconds of the compiled step (AOT
         compilation on a new shape bucket and the upload of the inputs
-        are excluded; compilation is accounted in ``compile_s``). Loss/edge-count/timing streams accumulate on the
-        engine for calibration and reporting.
+        are excluded; compilation is accounted in ``compile_s``).
+        Loss/edge-count/timing streams accumulate on the engine for
+        calibration and reporting.
         """
         import jax
         import jax.numpy as jnp
@@ -321,8 +323,13 @@ class ComputeEngine:
             self.compile_s += self.clock() - t0
             self.n_compiles += 1
         # host->device copies are asynchronous: finish them before the
-        # timer, or a reddit-width batch's ~4.4 GB upload lands in step_s
+        # timer, or a reddit-width batch's ~4.4 GB upload lands in step_s.
+        # The numpy arrays they read are freed only when JAX drains its
+        # deferred references, which it does from a Python GC callback or
+        # at the next dispatch; run a young-generation pass here, or the
+        # free of ~4.4 GB of host pages stalls the timed step
         jax.block_until_ready(args)
+        python_gc.collect(0)
         t0 = self.clock()
         out = self._exec[sig](*args)
         jax.block_until_ready(out)
