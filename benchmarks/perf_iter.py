@@ -10,7 +10,8 @@ Runs the real jitted GraphSAGE step (``repro.train.compute``) through the
 trainer's ``compute="measured"`` lane and emits
 ``results/bench/perf_iter.json`` with
 
-  * per-step wall times (warm-up compile excluded by the engine),
+  * per-step wall times of the compiled step (warm-up compile and host
+    preparation excluded by the engine),
   * on an accelerator, the roofline terms of the compiled SAGE step
     (``repro.launch.roofline.roofline_terms`` over the AOT executable's
     cost analysis + HLO text, priced at that chip's peaks) and the
@@ -159,8 +160,10 @@ def bench_sage(args) -> dict:
     step_s = np.asarray(rep["step_s"], np.float64)
     edges = np.asarray(rep["step_edges"], np.float64)
 
-    # refit t_base from the measured samples, replay the modeled lane
-    params_cal, fit = cal.calibrate_compute(edges, step_s)
+    # refit t_base from what the meter charged (host preparation plus the
+    # compiled step), replay the modeled lane
+    charged_s = step_s + np.asarray(rep["prep_s"], np.float64)
+    params_cal, fit = cal.calibrate_compute(edges, charged_s)
     cfg_mod = dataclasses.replace(
         cfg, compute="modeled",
         params=dataclasses.replace(cfg.params, t_base=params_cal.t_base),
@@ -182,8 +185,7 @@ def bench_sage(args) -> dict:
         eng = ComputeEngine(graph, cfg)
         mb = mbs[0][0]
         eng.step(
-            mb, np.asarray(graph.features[mb.input_nodes], np.float32),
-            key=(0, 0),
+            mb, np.asarray(graph.features[mb.input_nodes], np.float32)
         )
         exe = next(iter(eng._exec.values()))
         cost = exe.cost_analysis()

@@ -141,6 +141,7 @@ def run_smoke(n_nodes: int, avg_degree: float, n_feat: int, n_classes: int,
         n_steps=rep["n_steps"],
         losses=rep["losses"],
         step_s=rep["step_s"],
+        prep_s=rep["prep_s"],
         n_compiles=rep["n_compiles"],
         compile_s=rep["compile_s"],
         parity_max_diff=rep["parity_max_diff"],
@@ -193,6 +194,8 @@ def main(argv=None) -> int:
     log(f"losses: {r['losses']!r}")
     log(f"n_compiles: {r['n_compiles']}  compile_s: {r['compile_s']!r}")
     log(f"step_s (smoke timing, not a benchmark): {r['step_s']!r}")
+    log(f"prep_s (host build, upload and free; smoke timing): "
+        f"{r['prep_s']!r}")
     log(f"device-tier hits: {r['device_hits']}")
     log(f"rebuild windows (policy): {r['windows']!r}")
     log(f"peak_bytes_in_use: {r['peak_bytes']} of {r['bytes_limit']}")

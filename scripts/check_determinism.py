@@ -525,8 +525,7 @@ def _twin_compute_law(args) -> bool:
     for s in range(cfg.steps_per_epoch):
         mb = mbs[0][s]
         eng.step(
-            mb, np.asarray(graph.features[mb.input_nodes], np.float32),
-            key=(0, s),
+            mb, np.asarray(graph.features[mb.input_nodes], np.float32)
         )
     e_s, t_s = eng.calibration_samples()
     p2, _fit2 = cal.calibrate_compute(e_s, t_s)

@@ -1,5 +1,4 @@
 from repro.kernels.embedding_bag.ops import (  # noqa: F401
-    embedding_bag_pallas,
     gather_layout,
     gather_rows,
 )

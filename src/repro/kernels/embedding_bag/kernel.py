@@ -9,9 +9,9 @@ TPU's (8, 128) tiling, so rows travel in groups of eight.
 
 The table is laid out (R, 1, D) with D a multiple of 128: one row per
 leading index, so a DMA of one row is a whole tile and needs no sublane
-offset. Bag reduction and per-lookup weights happen outside the kernel
-(``ops.embedding_bag_pallas``); the gather itself is exact, so one index
-per bag at unit weight is bit-equal to ``table[idx]``.
+offset. Bag reduction and per-lookup weights, where a caller needs them,
+are a segment sum over the gathered rows; the gather itself is exact, so
+it is bit-equal to ``table[idx]``.
 """
 from __future__ import annotations
 

@@ -1,4 +1,4 @@
-"""Public EmbeddingBag ops: table layout, padding, bag reduction."""
+"""Public row-gather ops: table layout and index padding."""
 from __future__ import annotations
 
 import jax
@@ -35,22 +35,3 @@ def gather_rows(table: jax.Array, indices, n_feat: int,
         indices = jnp.pad(indices, (0, n_pad - n))
     out = gather_rows_kernel(indices, table, interpret=bool(interpret))
     return out[:n, :n_feat]
-
-
-def embedding_bag_pallas(
-    table: jax.Array,
-    indices: jax.Array,
-    segment_ids: jax.Array,
-    n_bags: int,
-    weights: jax.Array | None = None,
-    interpret: bool | None = None,
-) -> jax.Array:
-    """Sum-mode EmbeddingBag: Pallas row gather, then a weighted segment
-    sum. Segments may be unsorted; empty bags come out zero."""
-    d = table.shape[1]
-    rows = gather_rows(gather_layout(table), indices, d, interpret=interpret)
-    if weights is not None:
-        rows = rows * jnp.asarray(weights, table.dtype)[:, None]
-    return jax.ops.segment_sum(
-        rows, jnp.asarray(segment_ids, jnp.int32), num_segments=n_bags
-    )

@@ -12,11 +12,11 @@ resolved, with neighborhood aggregation dispatched through the
     format executes through the compiled XLA twin (``block_spmm_xla``),
     so the measured numbers are real compiled-step times everywhere.
 
-The edge-list -> block conversion is numpy preprocessing, cached per
-mini-batch key in an LRU bounded by device bytes, so a batch stepped again
-skips the conversion and upload while device memory stays bounded: at
-reddit width one batch's layer-0 tiles alone take ~4.3 GB, so such a batch
-is uploaded for its step and dropped after it.
+The edge-list -> block conversion is numpy preprocessing, run and
+uploaded afresh for every step and dropped after it: nothing keeps a batch
+on the device between steps (at reddit width one batch's layer-0 tiles
+alone take ~4.3 GB). That host work is timed as its own span, ``prep_s``,
+apart from the compiled step, ``step_s``; the meter is charged both.
 Dynamic block/src/dst counts are bucketed to powers of two so the jitted
 step compiles once per size bucket; compilation happens ahead-of-time
 (``.lower().compile()``) and is excluded from the measured step time.
@@ -31,9 +31,7 @@ into ``ring_collective_cost`` in place of the uncompressed payload.
 """
 from __future__ import annotations
 
-import gc as python_gc
 import time
-from collections import OrderedDict
 from typing import Callable
 
 import numpy as np
@@ -41,6 +39,10 @@ import numpy as np
 from repro.train import grad_compression as gc
 
 _SCHEMES = ("none", "int8", "topk")
+# The model is stated in float32. On TPU the backend's default runs a
+# float32 dot as one bf16 pass, which is a lower precision than that, so
+# the step (and the forward ``check_parity`` reads) pins its matmuls here.
+MATMUL_PRECISION = "highest"
 
 
 def _bucket(n: int) -> int:
@@ -89,7 +91,7 @@ class ComputeEngine:
 
     def __init__(self, graph, cfg, agg_impl: str = "auto",
                  clock: Callable[[], float] | None = None,
-                 cache_bytes: int = 1 << 30, tile: int = 128):
+                 tile: int = 128):
         import jax
 
         from repro import optim
@@ -137,11 +139,9 @@ class ComputeEngine:
         self._jit = jax.jit(self._step_fn)
         self._fwd_jit = jax.jit(self._forward)
         self._exec: dict = {}            # shape signature -> AOT executable
-        self._prep: OrderedDict = OrderedDict()   # mb id -> PreparedBatch
-        self._prep_nbytes: dict = {}              # mb id -> device bytes
-        self._cache_bytes = int(cache_bytes)
 
         self.losses: list[float] = []
+        self.prep_s: list[float] = []
         self.step_s: list[float] = []
         self.step_edges: list[int] = []
         self.compile_s = 0.0
@@ -150,32 +150,17 @@ class ComputeEngine:
         self._parity_tol = 2e-3
 
     # ------------------------------------------------------------ prepare
-    def prepare(self, mb, key=None):
-        """Block-sparse conversion + pow2 bucketing for one mini-batch.
-
-        Returns ``(layers, x_rows, n_edges)``; cached per ``key`` (the
-        worker passes ``(epoch, step)``) in an LRU holding at most
-        ``cache_bytes`` of device arrays. A batch larger than that is
-        never cached.
-        """
+    def prepare(self, mb):
+        """Block-sparse conversion + pow2 bucketing for one mini-batch,
+        uploaded: ``(layers, x_rows, n_edges)`` with ``layers`` on the
+        device."""
         import jax
 
-        if key is not None and key in self._prep:
-            self._prep.move_to_end(key)
-            return self._prep[key]
-        prep = self._prepare(mb)
-        nbytes = sum(a.nbytes for a in jax.tree.leaves(prep[0]))
-        if key is not None and nbytes <= self._cache_bytes:
-            self._prep[key] = prep
-            self._prep_nbytes[key] = nbytes
-            while sum(self._prep_nbytes.values()) > self._cache_bytes:
-                old, _ = self._prep.popitem(last=False)
-                del self._prep_nbytes[old]
-        return prep
+        host, x_rows, n_edges = self._prepare(mb)
+        return jax.device_put(host), x_rows, n_edges
 
     def _prepare(self, mb):
-        import jax.numpy as jnp
-
+        """``prepare`` on the host: the layers as numpy arrays."""
         from repro.kernels.segment_mm import to_block_sparse
 
         t = self.tile
@@ -210,19 +195,19 @@ class ComputeEngine:
             dst_pos = np.zeros(n_dst_pad, np.int32)
             dst_pos[:n_dst_true] = blk.dst_pos
             layer = {
-                "rows": jnp.asarray(rows),
-                "cols": jnp.asarray(cols),
-                "blocks": jnp.asarray(blocks),
-                "counts": jnp.asarray(np.maximum(indeg, 1.0)[:, None]),
-                "dst_pos": jnp.asarray(dst_pos),
+                "rows": rows,
+                "cols": cols,
+                "blocks": blocks,
+                "counts": np.maximum(indeg, 1.0)[:, None],
+                "dst_pos": dst_pos,
             }
             if i == len(mb.blocks) - 1:
                 labels = np.zeros(n_dst_pad, self.labels_np.dtype)
                 labels[:n_dst_true] = self.labels_np[blk.dst_nodes]
                 lmask = np.zeros(n_dst_pad, np.float32)
                 lmask[:n_dst_true] = blk.dst_mask.astype(np.float32)
-                layer["labels"] = jnp.asarray(labels)
-                layer["lmask"] = jnp.asarray(lmask)
+                layer["labels"] = labels
+                layer["lmask"] = lmask
             layers.append(layer)
             n_edges += int(blk.edge_mask.sum())
             src_rows = n_dst_pad
@@ -260,18 +245,21 @@ class ComputeEngine:
         return y / layer["counts"]
 
     def _forward(self, params, x_pad, layers):
-        """Block-path SAGE forward over prepared layers (padded rows)."""
+        """Block-path SAGE forward over prepared layers (padded rows), at
+        ``MATMUL_PRECISION``."""
         import jax
 
         h = x_pad
-        for i, layer in enumerate(layers):
-            lp = params[f"layer_{i}"]
-            agg = self._aggregate(layer, h)
-            h_dst_self = h[layer["dst_pos"]]
-            h_new = h_dst_self @ lp["w_self"] + agg @ lp["w_neigh"] + lp["b"]
-            if i < len(layers) - 1:
-                h_new = jax.nn.relu(h_new)
-            h = h_new
+        with jax.default_matmul_precision(MATMUL_PRECISION):
+            for i, layer in enumerate(layers):
+                lp = params[f"layer_{i}"]
+                agg = self._aggregate(layer, h)
+                h_dst_self = h[layer["dst_pos"]]
+                h_new = (h_dst_self @ lp["w_self"] + agg @ lp["w_neigh"]
+                         + lp["b"])
+                if i < len(layers) - 1:
+                    h_new = jax.nn.relu(h_new)
+                h = h_new
         return h
 
     def _step_fn(self, params, opt_state, error, x_pad, layers):
@@ -286,7 +274,10 @@ class ComputeEngine:
             logits = self._forward(p, x_pad, layers)
             return cross_entropy(logits, last["labels"], last["lmask"])
 
-        loss, grads = jax.value_and_grad(loss_fn)(params)
+        # the backward pass (the kernel's custom VJP included) is traced
+        # here, so it runs at the forward's precision too
+        with jax.default_matmul_precision(MATMUL_PRECISION):
+            loss, grads = jax.value_and_grad(loss_fn)(params)
         if self.scheme == "int8":
             grads, error = gc.compress_int8(grads, error)
         elif self.scheme == "topk":
@@ -295,25 +286,28 @@ class ComputeEngine:
         return optim.apply_updates(params, upd), opt_state, error, loss
 
     # --------------------------------------------------------------- step
-    def step(self, mb, x_in: np.ndarray, key=None) -> float:
+    def step(self, mb, x_in: np.ndarray) -> float:
         """One measured forward/backward/optimizer step.
 
         ``x_in`` are the resolved feature rows for ``mb.input_nodes``.
-        Returns the measured wall seconds of the compiled step (AOT
-        compilation on a new shape bucket and the upload of the inputs
-        are excluded; compilation is accounted in ``compile_s``).
-        Loss/edge-count/timing streams accumulate on the engine for
-        calibration and reporting.
+        Two spans are timed: ``prep_s``, the host build of the block
+        layers and padded input, their upload (waited for) and the free of
+        the host copies; and ``step_s``, the compiled step alone, which is
+        what ``calibration_samples`` fits. AOT compilation on a new shape
+        bucket and the first step's parity check are in neither
+        (compilation is accounted in ``compile_s``). Returns the sum of
+        the two spans, the step's compute time as the meter charges it.
         """
         import jax
-        import jax.numpy as jnp
 
-        layers, x_rows, n_edges = self.prepare(mb, key)
+        t = self.clock()
+        host, x_rows, n_edges = self._prepare(mb)
+        x_pad = self.pad_input(np.asarray(x_in, np.float32), x_rows)
+        layers, x_dev = jax.block_until_ready(jax.device_put((host, x_pad)))
+        prep = self.clock() - t
         if self.parity_max_diff is None:
             self.check_parity(mb, x_in, _prep=(layers, x_rows))
-        x_pad = self.pad_input(np.asarray(x_in, np.float32), x_rows)
-        args = (self.params, self.opt_state, self.error, jnp.asarray(x_pad),
-                layers)
+        args = (self.params, self.opt_state, self.error, x_dev, layers)
         sig = (x_pad.shape,) + tuple(
             (l["rows"].shape[0], l["counts"].shape[0]) for l in layers
         )
@@ -322,23 +316,22 @@ class ComputeEngine:
             self._exec[sig] = self._jit.lower(*args).compile()
             self.compile_s += self.clock() - t0
             self.n_compiles += 1
-        # host->device copies are asynchronous: finish them before the
-        # timer, or a reddit-width batch's ~4.4 GB upload lands in step_s.
-        # The numpy arrays they read are freed only when JAX drains its
-        # deferred references, which it does from a Python GC callback or
-        # at the next dispatch; run a young-generation pass here, or the
-        # free of ~4.4 GB of host pages stalls the timed step
-        jax.block_until_ready(args)
-        python_gc.collect(0)
         t0 = self.clock()
         out = self._exec[sig](*args)
         jax.block_until_ready(out)
         dt = self.clock() - t0
+        # the host copies the upload read are held past the timed step and
+        # dropped here, so their free (~4.4 GB at reddit width) is charged
+        # to prep_s and not to the compiled step
+        t = self.clock()
+        del host, x_pad
+        prep += self.clock() - t
         self.params, self.opt_state, self.error, loss = out
         self.losses.append(float(loss))
+        self.prep_s.append(float(prep))
         self.step_s.append(float(dt))
         self.step_edges.append(int(n_edges))
-        return float(dt)
+        return float(prep + dt)
 
     # ------------------------------------------------------------- parity
     def check_parity(self, mb, x_in: np.ndarray, tol: float | None = None,
@@ -348,10 +341,9 @@ class ComputeEngine:
         The reference is ``sage.apply_blocks`` (per-edge gather +
         ``common.scatter_sum``/mean) on the UNPADDED blocks; the block
         path must agree on every valid dst row within float-accumulation
-        tolerance (summation order differs between the two). Both sides
-        run at full float32 matmul precision, so the check sees the
-        block-sparse aggregation (tiles, padding, kernel) and not the
-        backend's default matmul precision (one bf16 pass on TPU).
+        tolerance (summation order differs between the two). The block
+        path runs at ``MATMUL_PRECISION``, as the trained step does; the
+        reference runs at full float32.
         """
         import jax
         import jax.numpy as jnp
@@ -373,8 +365,8 @@ class ComputeEngine:
             }
             for b in mb.blocks
         ]
+        got = self._fwd_jit(self.params, jnp.asarray(x_pad), layers)
         with jax.default_matmul_precision("highest"):
-            got = self._fwd_jit(self.params, jnp.asarray(x_pad), layers)
             ref = sage.apply_blocks(
                 self.params, self.mcfg,
                 jnp.asarray(np.asarray(x_in, np.float32)), ref_blocks,
@@ -409,6 +401,7 @@ class ComputeEngine:
         return {
             "n_steps": len(self.step_s),
             "losses": list(self.losses),
+            "prep_s": list(self.prep_s),
             "step_s": list(self.step_s),
             "step_edges": list(self.step_edges),
             "compile_s": self.compile_s,

@@ -423,7 +423,9 @@ class TrainerWorker:
             flops = 2.0 * n_edges * width
             nbyte = 4.0 * n_edges * width
             args = {"n_edges": n_edges, "flops_est": flops,
-                    "bytes_est": nbyte}
+                    "bytes_est": nbyte,
+                    "prep_s": self.engine.prep_s[-1],
+                    "step_s": self.engine.step_s[-1]}
             peaks = self.engine.peaks
             if peaks is not None:
                 comp_s, mem_s = flops / peaks.flops, nbyte / peaks.hbm_bw
@@ -708,12 +710,13 @@ class TrainerWorker:
         )
         if self.engine is not None:
             # measured lane: the real jitted step over this batch's
-            # resolved payload rows; its wall time is charged where the
-            # modeled lane charges the t_base constant
+            # resolved payload rows; its wall time, host preparation
+            # included, is charged where the modeled lane charges the
+            # t_base constant
             mb = self.mbs[epoch][step]
             x_in = self._resolve_features(input_nodes, remote_ids,
                                           device_rows)
-            t_compute = self.engine.step(mb, x_in, key=(epoch, step))
+            t_compute = self.engine.step(mb, x_in)
         else:
             t_compute = self.t_base
         if self.tracer.enabled:

@@ -103,7 +103,7 @@ class TestBlockAggregation:
 
 
 # ---------------------------------------------------------------------------
-# device plumbing: peak table, prepared-batch bound, compile cache
+# device plumbing: peak table, prepared batches, step spans, compile cache
 # ---------------------------------------------------------------------------
 
 class TestDevicePlumbing:
@@ -125,28 +125,72 @@ class TestDevicePlumbing:
         eng = ComputeEngine(graph, cfg)
         assert eng.peaks is None and eng.agg_impl == "xla"
 
-    @pytest.mark.parametrize("budget_batches", [0, 1, 3])
-    def test_prepared_batches_bounded_by_bytes(self, budget_batches):
+    def test_prepare_uploads_canonical_layers(self):
         from repro.train import gnn_trainer as gt
         from repro.train.compute import ComputeEngine
 
-        cfg = gt.RunConfig(**dict(_PIN_CFG, n_epochs=1, steps_per_epoch=4))
+        cfg = gt.RunConfig(**dict(_PIN_CFG, n_epochs=1, steps_per_epoch=1))
         graph, _owner, _traces, mbs = gt.build_trace(cfg)
-        probe = ComputeEngine(graph, cfg)
-        sizes = [
-            sum(a.nbytes for a in jax.tree.leaves(probe.prepare(mb)[0]))
+        layers, x_rows, n_edges = ComputeEngine(graph, cfg).prepare(mbs[0][0])
+        assert x_rows % 128 == 0 and n_edges > 0
+        for layer in layers:
+            assert all(isinstance(a, jax.Array) for a in layer.values())
+            assert layer["rows"].dtype == jnp.int32
+            assert layer["blocks"].dtype == jnp.float32
+        assert layers[-1]["labels"].dtype == jnp.int32
+
+    @pytest.mark.parametrize("n_steps", [1, 3])
+    def test_step_times_prep_and_compiled_step_apart(self, n_steps):
+        """Under a clock that advances 1 ms per read, the compiled step
+        spans one read pair, host preparation two (before the step and the
+        free after it), and ``step`` returns their sum, the charged time."""
+        from repro.train import gnn_trainer as gt
+        from repro.train.compute import ComputeEngine
+
+        class _VClock:
+            t = 0.0
+
+            def __call__(self):
+                self.t += 1e-3
+                return self.t
+
+        cfg = gt.RunConfig(**dict(_PIN_CFG, n_epochs=1,
+                                  steps_per_epoch=n_steps))
+        graph, _owner, _traces, mbs = gt.build_trace(cfg)
+        eng = ComputeEngine(graph, cfg, clock=_VClock())
+        charged = [
+            eng.step(mb, np.asarray(graph.features[mb.input_nodes],
+                                    np.float32))
             for mb in mbs[0]
         ]
-        cap = max(sizes) * budget_batches
-        eng = ComputeEngine(graph, cfg, cache_bytes=cap)
-        for s, mb in enumerate(mbs[0]):
-            eng.prepare(mb, key=(0, s))
-            assert sum(eng._prep_nbytes.values()) <= cap
-        assert len(eng._prep) <= budget_batches
-        if budget_batches:
-            # the newest batch is cached and served again without rework
-            last = eng._prep[(0, 3)]
-            assert eng.prepare(mbs[0][3], key=(0, 3)) is last
+        rep = eng.report()
+        assert rep["step_s"] == pytest.approx([1e-3] * n_steps)
+        assert rep["prep_s"] == pytest.approx([2e-3] * n_steps)
+        assert charged == pytest.approx([3e-3] * n_steps)
+        assert rep["n_compiles"] == 1
+
+    @pytest.mark.parametrize("agg_impl", ["xla", "pallas"])
+    def test_step_lowers_every_dot_at_model_precision(self, agg_impl):
+        """The trained step, backward pass included, runs its matmuls at
+        the model's float32 precision, not the backend's default."""
+        import re
+
+        from repro.train import gnn_trainer as gt
+        from repro.train.compute import ComputeEngine
+
+        cfg = gt.RunConfig(**dict(_PIN_CFG, n_epochs=1, steps_per_epoch=1))
+        graph, _owner, _traces, mbs = gt.build_trace(cfg)
+        eng = ComputeEngine(graph, cfg, agg_impl=agg_impl)
+        mb = mbs[0][0]
+        layers, x_rows, _ = eng.prepare(mb)
+        x = eng.pad_input(
+            np.asarray(graph.features[mb.input_nodes], np.float32), x_rows
+        )
+        text = eng._jit.lower(eng.params, eng.opt_state, eng.error, x,
+                              layers).as_text()
+        dots = re.findall(r"stablehlo\.dot_general.*", text)
+        assert dots
+        assert all("precision = [HIGHEST, HIGHEST]" in d for d in dots)
 
     def test_compile_cache_env_wins_else_fixed_path(self, monkeypatch):
         from repro.launch import compile_cache as cc
@@ -321,8 +365,7 @@ class TestComputeEngine:
         for s in range(cfg.steps_per_epoch):
             mb = mbs[0][s]
             dt = eng.step(
-                mb, np.asarray(graph.features[mb.input_nodes], np.float32),
-                key=(0, s),
+                mb, np.asarray(graph.features[mb.input_nodes], np.float32)
             )
             assert dt > 0.0
         rep = eng.report()
