@@ -276,7 +276,7 @@ TWINS: tuple[Twin, ...] = (
         name="worker-rpc-wall",
         kind="shared-helper",
         helper=Site(_CM, "rpc_wall_s"),
-        sites=(Site("train/worker.py", "TrainerWorker.step"),),
+        sites=(Site("train/worker.py", "TrainerWorker._step"),),
         note="the worker's per-owner estimator feeding the controller "
              "deque must stay the shared Eq. 4 closed form",
     ),

@@ -21,6 +21,8 @@ import dataclasses
 
 import numpy as np
 
+from repro.obs.wall import NULL_SPANS
+
 
 @dataclasses.dataclass
 class RebuildPlan:
@@ -68,7 +70,9 @@ def _largest_remainder(weights: np.ndarray, total: int) -> np.ndarray:
 class DoubleBufferedCache:
     """Active/pending hot-node cache with per-owner capacity allocation."""
 
-    def __init__(self, capacity: int, owner_of: np.ndarray, n_owners: int):
+    def __init__(self, capacity: int, owner_of: np.ndarray, n_owners: int,
+                 spans=NULL_SPANS):
+        self.spans = spans  # host-clock spans: cache.plan, cache.probe
         self.capacity = int(capacity)
         self.owner_of = np.asarray(owner_of)
         self.n_owners = int(n_owners)
@@ -85,6 +89,10 @@ class DoubleBufferedCache:
         window_batches: per-batch arrays of *remote* node ids needed.
         weights: (n_owners,) RL cost weights -> per-owner capacity quota.
         """
+        with self.spans.span("cache.plan"):
+            return self._plan_window(window_batches, weights)
+
+    def _plan_window(self, window_batches, weights) -> RebuildPlan:
         weights = np.asarray(weights, np.float64)
         weights = weights / max(weights.sum(), 1e-9)
 
@@ -171,6 +179,10 @@ class DoubleBufferedCache:
         """Record hits/misses for one batch into every sink (ONE lookup —
         epoch- and window-scoped stats share the same searchsorted probe);
         returns the miss ids."""
+        with self.spans.span("cache.probe"):
+            return self._access(remote_ids, stat_sinks)
+
+    def _access(self, remote_ids, stat_sinks) -> np.ndarray:
         remote_ids = np.asarray(remote_ids).ravel()
         hit, _ = self.lookup(remote_ids)
         n_hit, n_miss = int(hit.sum()), int((~hit).sum())

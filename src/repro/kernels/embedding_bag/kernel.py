@@ -65,4 +65,5 @@ def gather_rows_kernel(
         ),
         out_shape=jax.ShapeDtypeStruct((l, d), table.dtype),
         interpret=interpret,
+        name="gather_rows_kernel",  # a stable name in the profiler trace
     )(indices, table)

@@ -114,6 +114,9 @@ def _spmm_call(out_ids, in_ids, tile_ids, blocks, x, n_out_blocks,
         ),
         out_shape=jax.ShapeDtypeStruct((n_out_blocks * out_rows, f), x.dtype),
         interpret=interpret,
+        # stable names for the profiler trace's kernel events
+        name=("block_spmm_kernel_transposed" if transpose
+              else "block_spmm_kernel"),
     )(*prefetch, blocks, x)
 
 

@@ -13,21 +13,30 @@ length; the payload table itself is zero-padded to the cache capacity (and
 its width to the kernel's 128-lane layout) so its shape is static for the
 whole run. The kernel compiles on TPU and runs in the Pallas interpreter
 only on CPU (``segment_mm.default_interpret``).
+
+Transfers are counted exactly: ``h2d_bytes`` for the table uploads,
+``d2h_bytes`` for the gathered rows copied back. With the owning
+worker's span recorder, ``load`` is the span ``tier.load`` and a gather
+``tier.gather``, with the lazy table upload as its child
+``tier.table_upload``.
 """
 from __future__ import annotations
 
+import jax
 import numpy as np
 
 from repro.core.windowed_cache import DoubleBufferedCache, RebuildPlan
 from repro.kernels.embedding_bag import gather_layout, gather_rows
+from repro.obs.wall import NULL_SPANS
 
 
 class DevicePayloadTier:
     """Payload rows for the cache's active buffer + kernel-served hit path."""
 
     def __init__(self, cache: DoubleBufferedCache, n_feat: int,
-                 dtype=np.float32):
+                 dtype=np.float32, spans=NULL_SPANS):
         self.cache = cache
+        self.spans = spans
         self.n_feat = int(n_feat)
         self.dtype = np.dtype(dtype)
         self.capacity = int(cache.capacity)
@@ -35,6 +44,8 @@ class DevicePayloadTier:
         self._table = None          # device (capacity, 1, D_pad) gather layout
         self.n_loads = 0
         self.rows_gathered = 0
+        self.h2d_bytes = 0
+        self.d2h_bytes = 0
 
     @property
     def resident_bytes(self) -> float:
@@ -52,6 +63,10 @@ class DevicePayloadTier:
         ``plan.hot_nodes[plan.fetched]`` when the builder already gathered
         them; otherwise they are peeked from the backing store.
         """
+        with self.spans.span("tier.load"):
+            self._load(plan, peek_fn, fetched_rows)
+
+    def _load(self, plan, peek_fn, fetched_rows) -> None:
         ids = plan.hot_nodes
         new_payload = np.zeros((len(ids), self.n_feat), self.dtype)
         old_active = self.cache.active_nodes
@@ -75,16 +90,25 @@ class DevicePayloadTier:
         n = len(slot_idx)
         if n == 0 or len(self._payload) == 0:
             return np.zeros((0, self.n_feat), self.dtype)
-        if self._table is None:
-            padded = np.zeros((self.capacity, self.n_feat), self.dtype)
-            padded[: len(self._payload)] = self._payload
-            self._table = gather_layout(padded)
-        bucket = 1 << (n - 1).bit_length()
-        idx = np.zeros(bucket, np.int32)  # pad lookups read slot 0, cut below
-        idx[:n] = np.asarray(slot_idx, np.int32)
-        out = gather_rows(self._table, idx, self.n_feat)
+        with self.spans.span("tier.gather") as span:
+            if self._table is None:
+                padded = np.zeros((self.capacity, self.n_feat), self.dtype)
+                padded[: len(self._payload)] = self._payload
+                # waited for, so that the span times the upload itself
+                with self.spans.span("tier.table_upload",
+                                     h2d_bytes=padded.nbytes):
+                    self._table = jax.block_until_ready(
+                        gather_layout(padded))
+                self.h2d_bytes += padded.nbytes
+            bucket = 1 << (n - 1).bit_length()
+            idx = np.zeros(bucket, np.int32)  # pad lookups read slot 0
+            idx[:n] = np.asarray(slot_idx, np.int32)
+            out = gather_rows(self._table, idx, self.n_feat)
+            self.d2h_bytes += out.nbytes
+            span.note(d2h_bytes=out.nbytes)
+            rows = np.asarray(out)[:n].astype(self.dtype)
         self.rows_gathered += n
-        return np.asarray(out)[:n].astype(self.dtype)
+        return rows
 
     def gather(self, remote_ids: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
         """(hit_mask, rows for the hits) for a batch of remote node ids."""

@@ -23,9 +23,12 @@ step compiles once per size bucket; compilation happens ahead-of-time
 
 The block path is parity-asserted against the ``models/gnn/common``
 scatter reference (``check_parity``, run automatically on the first
-step). On an accelerator the engine prices its steps at the chip's
-published peaks (``launch.roofline.PEAKS``); an accelerator missing from
-that table is an error. Gradient sync flows through ``grad_compression``
+step). The step's phases are host-clock spans of the worker's
+``repro.obs.wall`` recorder (``engine.build``, ``engine.pad``,
+``engine.upload``, ``engine.parity``, ``engine.compile``, ``engine.run``,
+``engine.free`` inside ``engine.step``), and its uploads are counted exactly:
+``h2d_bytes``, and the 128x128 ``tiles`` uploaded of which ``pad_tiles``
+are power-of-two padding. Gradient sync flows through ``grad_compression``
 with error feedback; ``sync_wire_bytes`` is what the cluster driver feeds
 into ``ring_collective_cost`` in place of the uncompressed payload.
 """
@@ -36,6 +39,7 @@ from typing import Callable
 
 import numpy as np
 
+from repro.obs.wall import NULL_SPANS
 from repro.train import grad_compression as gc
 
 _SCHEMES = ("none", "int8", "topk")
@@ -86,17 +90,18 @@ class ComputeEngine:
 
     ``clock`` is injectable (monotonic, ``time.perf_counter`` by default)
     so the determinism harness can drive the measured lane with a virtual
-    clock and pin the timing -> calibration plumbing numerically.
+    clock and pin the timing -> calibration plumbing numerically. ``spans``
+    is the owning worker's host-clock span recorder; it never reads
+    ``clock``.
     """
 
     def __init__(self, graph, cfg, agg_impl: str = "auto",
                  clock: Callable[[], float] | None = None,
-                 tile: int = 128):
+                 tile: int = 128, spans=NULL_SPANS):
         import jax
 
         from repro import optim
         from repro.kernels.segment_mm import default_interpret
-        from repro.launch.roofline import device_peaks
 
         scheme = getattr(cfg, "grad_compression", "none")
         if scheme not in _SCHEMES:
@@ -110,14 +115,7 @@ class ComputeEngine:
 
         from repro.models.gnn import sage
 
-        device = jax.devices()[0]
-        self.device_kind = device.device_kind
-        # per-chip peaks for the roofline terms; None on CPU, where a wall
-        # time prices nothing
-        self.peaks = (
-            None if device.platform == "cpu"
-            else device_peaks(device.device_kind)
-        )
+        self.device_kind = jax.devices()[0].device_kind
         self.graph = graph
         self.mcfg = sage_config(graph)
         self.tile = int(tile)
@@ -125,6 +123,7 @@ class ComputeEngine:
         self.scheme = scheme
         self.topk_frac = float(getattr(cfg, "topk_frac", 0.05))
         self.clock = clock or time.perf_counter
+        self.spans = spans
         self.params, _ = sage.init(jax.random.PRNGKey(cfg.seed), self.mcfg)
         self.opt = optim.adamw(3e-3)  # greenlint: literal-ok — must match
         # the modeled lane's _init_model lr exactly; plumbing a config
@@ -146,6 +145,9 @@ class ComputeEngine:
         self.step_edges: list[int] = []
         self.compile_s = 0.0
         self.n_compiles = 0
+        self.h2d_bytes = 0       # everything step uploads
+        self.tiles = 0           # 128x128 tiles uploaded, all layers
+        self.pad_tiles = 0       # of which power-of-two padding
         self.parity_max_diff: float | None = None
         self._parity_tol = 2e-3
 
@@ -156,16 +158,18 @@ class ComputeEngine:
         device."""
         import jax
 
-        host, x_rows, n_edges = self._prepare(mb)
+        host, x_rows, n_edges, _ = self._prepare(mb)
         return jax.device_put(host), x_rows, n_edges
 
     def _prepare(self, mb):
-        """``prepare`` on the host: the layers as numpy arrays."""
+        """``prepare`` on the host: the layers as numpy arrays, and how many
+        of their tiles are power-of-two padding."""
         from repro.kernels.segment_mm import to_block_sparse
 
         t = self.tile
         layers = []
         n_edges = 0
+        pad_tiles = 0
         n_src_rows = _bucket(-(-len(mb.blocks[0].src_nodes) // t)) * t
         src_rows = n_src_rows
         for i, blk in enumerate(mb.blocks):
@@ -180,6 +184,7 @@ class ComputeEngine:
             nbp = _bucket(len(rows))
             if nbp > len(rows):
                 pad = nbp - len(rows)
+                pad_tiles += pad
                 # padding blocks stay zero and point at the last row-block
                 # (rows stay sorted; they accumulate nothing)
                 rows = np.concatenate(
@@ -211,7 +216,7 @@ class ComputeEngine:
             layers.append(layer)
             n_edges += int(blk.edge_mask.sum())
             src_rows = n_dst_pad
-        return tuple(layers), n_src_rows, n_edges
+        return tuple(layers), n_src_rows, n_edges, pad_tiles
 
     def pad_input(self, x_in: np.ndarray, x_rows: int) -> np.ndarray:
         x = np.zeros((x_rows, self.mcfg.d_in), np.float32)
@@ -297,37 +302,55 @@ class ComputeEngine:
         bucket and the first step's parity check are in neither
         (compilation is accounted in ``compile_s``). Returns the sum of
         the two spans, the step's compute time as the meter charges it.
+        ``h2d_bytes``, ``tiles`` and ``pad_tiles`` count the upload (the
+        first step's parity check uploads its own copies, not counted).
         """
         import jax
 
-        t = self.clock()
-        host, x_rows, n_edges = self._prepare(mb)
-        x_pad = self.pad_input(np.asarray(x_in, np.float32), x_rows)
-        layers, x_dev = jax.block_until_ready(jax.device_put((host, x_pad)))
-        prep = self.clock() - t
-        if self.parity_max_diff is None:
-            self.check_parity(mb, x_in, _prep=(layers, x_rows))
-        args = (self.params, self.opt_state, self.error, x_dev, layers)
-        sig = (x_pad.shape,) + tuple(
-            (l["rows"].shape[0], l["counts"].shape[0]) for l in layers
-        )
-        if sig not in self._exec:
-            t0 = self.clock()
-            self._exec[sig] = self._jit.lower(*args).compile()
-            self.compile_s += self.clock() - t0
-            self.n_compiles += 1
-        t0 = self.clock()
-        out = self._exec[sig](*args)
-        jax.block_until_ready(out)
-        dt = self.clock() - t0
-        # the host copies the upload read are held past the timed step and
-        # dropped here, so their free (~4.4 GB at reddit width) is charged
-        # to prep_s and not to the compiled step
-        t = self.clock()
-        del host, x_pad
-        prep += self.clock() - t
-        self.params, self.opt_state, self.error, loss = out
-        self.losses.append(float(loss))
+        spans = self.spans
+        with spans.span("engine.step"):
+            t = self.clock()
+            with spans.span("engine.build"):
+                host, x_rows, n_edges, pad_tiles = self._prepare(mb)
+            with spans.span("engine.pad"):
+                x_pad = self.pad_input(np.asarray(x_in, np.float32), x_rows)
+            nbytes = sum(a.nbytes for a in jax.tree.leaves((host, x_pad)))
+            tiles = sum(len(layer["rows"]) for layer in host)
+            self.h2d_bytes += nbytes
+            self.tiles += tiles
+            self.pad_tiles += pad_tiles
+            with spans.span("engine.upload", h2d_bytes=nbytes, tiles=tiles,
+                            pad_tiles=pad_tiles):
+                layers, x_dev = jax.block_until_ready(
+                    jax.device_put((host, x_pad)))
+            prep = self.clock() - t
+            if self.parity_max_diff is None:
+                with spans.span("engine.parity"):
+                    self.check_parity(mb, x_in, _prep=(layers, x_rows))
+            args = (self.params, self.opt_state, self.error, x_dev, layers)
+            sig = (x_pad.shape,) + tuple(
+                (l["rows"].shape[0], l["counts"].shape[0]) for l in layers
+            )
+            if sig not in self._exec:
+                with spans.span("engine.compile"):
+                    t0 = self.clock()
+                    self._exec[sig] = self._jit.lower(*args).compile()
+                    self.compile_s += self.clock() - t0
+                self.n_compiles += 1
+            with spans.span("engine.run"):
+                t0 = self.clock()
+                out = self._exec[sig](*args)
+                jax.block_until_ready(out)
+                dt = self.clock() - t0
+            # the host copies the upload read are held past the timed step
+            # and dropped here, so their free (~4.4 GB at reddit width) is
+            # charged to prep_s and not to the compiled step
+            with spans.span("engine.free"):
+                t = self.clock()
+                del host, x_pad
+                prep += self.clock() - t
+            self.params, self.opt_state, self.error, loss = out
+            self.losses.append(float(loss))
         self.prep_s.append(float(prep))
         self.step_s.append(float(dt))
         self.step_edges.append(int(n_edges))

@@ -33,6 +33,7 @@ from repro.core.windowed_cache import CacheStats, DoubleBufferedCache
 from repro.graph.features import ShardedFeatureStore
 from repro.net.fabric import NetClock
 from repro.obs.tracer import NULL_TRACER, Tracer
+from repro.obs.wall import NULL_SPANS, SpanRecorder
 
 WINDOWED_METHODS = ("static_w", "heuristic", "greendygnn", "greendygnn_nocw")
 ADAPTIVE_METHODS = ("heuristic", "greendygnn", "greendygnn_nocw")
@@ -71,14 +72,15 @@ def build_store(graph, owner: np.ndarray, rank: int, n_parts: int,
     )
 
 
-def build_cache(cfg, graph, owner_idx_map: np.ndarray
+def build_cache(cfg, graph, owner_idx_map: np.ndarray, spans=NULL_SPANS
                 ) -> DoubleBufferedCache | None:
     """Hot-set cache for cached methods (None for dgl/bgl)."""
     windowed = cfg.method in WINDOWED_METHODS
     if not (windowed or cfg.method == "rapidgnn"):
         return None
     capacity = int(cfg.cache_frac * graph.n_nodes)
-    return DoubleBufferedCache(capacity, owner_idx_map, cfg.n_parts - 1)
+    return DoubleBufferedCache(capacity, owner_idx_map, cfg.n_parts - 1,
+                               spans=spans)
 
 
 def build_controller(cfg, params, n_owners: int,
@@ -170,6 +172,11 @@ class TrainerWorker:
     ``cluster=True`` marks the worker as one of P trainers sharing the
     fabric: transfers carry ``requester=rank`` and the worker's own
     virtual clock, and the shared fabric's ticked clock is left alone.
+
+    ``spans`` is the worker's host-clock span recorder (``repro.obs.wall``),
+    handed to its engine, device tier and cache. It records while
+    ``spans.enabled`` is set or a profiler trace is being taken; it reads
+    no virtual clock and never writes into the greentrace ``tracer``.
     """
 
     def __init__(
@@ -207,8 +214,10 @@ class TrainerWorker:
         self.owner_idx_map = self.store.owner_index(np.arange(graph.n_nodes))
         self.bytes_per_row = self.store.bytes_per_row
 
+        self.spans = SpanRecorder(rank=self.rank)
         self.windowed = cfg.method in WINDOWED_METHODS
-        self.cache = build_cache(cfg, graph, self.owner_idx_map)
+        self.cache = build_cache(cfg, graph, self.owner_idx_map,
+                                 spans=self.spans)
         self.controller = build_controller(
             cfg, params, self.n_owners, observe_headroom=self.tiered
         )
@@ -239,7 +248,8 @@ class TrainerWorker:
                 if graph.features is not None
                 else graph.feature_source.n_feat
             )
-            self.device = DevicePayloadTier(self.cache, n_feat)
+            self.device = DevicePayloadTier(self.cache, n_feat,
+                                            spans=self.spans)
 
         if cfg.compute not in ("modeled", "measured"):
             raise ValueError(
@@ -251,7 +261,7 @@ class TrainerWorker:
             # wall time replaces the modeled t_base charge below
             from repro.train.compute import ComputeEngine
 
-            self.engine = ComputeEngine(graph, cfg)
+            self.engine = ComputeEngine(graph, cfg, spans=self.spans)
         self.model_state = None
         if cfg.run_model and self.engine is None:
             from repro.train import gnn_trainer as gt
@@ -324,38 +334,46 @@ class TrainerWorker:
         path, which reconstructs it from Eq. 4 where needed)."""
         from repro.train import gnn_trainer as gt
 
-        rows = np.asarray(per_owner_rows, np.float64)
-        if self.fabric is not None:
-            tr = self.fabric.transfer(
-                rows, self.bytes_per_row,
-                requester=self.requester, clock=self._clk,
+        with self.spans.span("worker.net"):
+            rows = np.asarray(per_owner_rows, np.float64)
+            if self.fabric is not None:
+                tr = self.fabric.transfer(
+                    rows, self.bytes_per_row,
+                    requester=self.requester, clock=self._clk,
+                )
+                return (*tr.astuple(), tr.per_owner_s)
+            return (
+                *gt._fetch_time(self.params, rows, delta, self.bytes_per_row),
+                None,
             )
-            return (*tr.astuple(), tr.per_owner_s)
-        return (
-            *gt._fetch_time(self.params, rows, delta, self.bytes_per_row),
-            None,
-        )
 
     def _net_chunked(self, per_owner_rows, delta, at_s=None):
         """Fine-grained DistTensor round (DGL/BGL) through the substrate."""
         from repro.train import gnn_trainer as gt
 
         cfg = self.cfg
-        rows = np.asarray(per_owner_rows, np.float64)
-        if self.fabric is not None:
-            tr = self.fabric.transfer(
-                rows, self.bytes_per_row, at_s=at_s,
-                chunk=cfg.dgl_chunk, concurrency=cfg.dgl_concurrency,
-                requester=self.requester, clock=self._clk,
+        with self.spans.span("worker.net"):
+            rows = np.asarray(per_owner_rows, np.float64)
+            if self.fabric is not None:
+                tr = self.fabric.transfer(
+                    rows, self.bytes_per_row, at_s=at_s,
+                    chunk=cfg.dgl_chunk, concurrency=cfg.dgl_concurrency,
+                    requester=self.requester, clock=self._clk,
+                )
+                return (*tr.astuple(), tr.per_owner_s)
+            return (
+                *gt._chunked_fetch_time(
+                    self.params, rows, delta, self.bytes_per_row,
+                    cfg.dgl_chunk, cfg.dgl_concurrency,
+                ),
+                None,
             )
-            return (*tr.astuple(), tr.per_owner_s)
-        return (
-            *gt._chunked_fetch_time(
-                self.params, rows, delta, self.bytes_per_row,
-                cfg.dgl_chunk, cfg.dgl_concurrency,
-            ),
-            None,
-        )
+
+    def _touch(self, ids):
+        """``store.touch``: stage ``ids``' host-tier blocks (the simulator's
+        host-tier bookkeeping, a ``worker.net`` span like the fabric's)."""
+        with self.spans.span("worker.net"):
+            return self.store.touch(ids)
 
     # ------------------------------------------------------------- controller
     def _decide(self, exposed_stall: float, step: int):
@@ -363,43 +381,44 @@ class TrainerWorker:
         from repro.train import gnn_trainer as gt
 
         cfg = self.cfg
-        obs_stats = (
-            self.window_stats
-            if self.window_stats.hits + self.window_stats.misses
-            else self.epoch_stats
-        )
-        stats = gt._controller_stats(
-            obs_stats, self.meter, self.t_base, self.e_baseline,
-            step, cfg.steps_per_epoch, self.n_owners,
-            snapshot=self.meter_snapshot,
-            rebuild_stall=exposed_stall,
-            headroom=(self.store.headroom() if self.tiered else 1.0),
-        )
-        w, ww, action = self.controller.decide(stats)
-        if cfg.method == "greendygnn_nocw":
-            ww = np.full(self.n_owners, 1.0 / self.n_owners)
-        if self.tracer.enabled:
-            # per-boundary DQN decision: the observation vector the policy
-            # saw, and the (W, allocation) it chose
-            self.tracer.instant(
-                "controller", "decide", self.meter.wall_s, step=step,
-                args={
-                    "action": int(action),
-                    "window": int(w),
-                    "weights": [float(x) for x in ww],
-                    "sigma_hat": [
-                        float(x) for x in np.atleast_1d(
-                            self.controller.last_sigma
-                        )
-                    ],
-                    "obs": [
-                        float(x) for x in np.atleast_1d(
-                            self.controller.last_state
-                        )
-                    ],
-                },
+        with self.spans.span("worker.decide"):
+            obs_stats = (
+                self.window_stats
+                if self.window_stats.hits + self.window_stats.misses
+                else self.epoch_stats
             )
-        return w, ww
+            stats = gt._controller_stats(
+                obs_stats, self.meter, self.t_base, self.e_baseline,
+                step, cfg.steps_per_epoch, self.n_owners,
+                snapshot=self.meter_snapshot,
+                rebuild_stall=exposed_stall,
+                headroom=(self.store.headroom() if self.tiered else 1.0),
+            )
+            w, ww, action = self.controller.decide(stats)
+            if cfg.method == "greendygnn_nocw":
+                ww = np.full(self.n_owners, 1.0 / self.n_owners)
+            if self.tracer.enabled:
+                # per-boundary DQN decision: the observation vector the policy
+                # saw, and the (W, allocation) it chose
+                self.tracer.instant(
+                    "controller", "decide", self.meter.wall_s, step=step,
+                    args={
+                        "action": int(action),
+                        "window": int(w),
+                        "weights": [float(x) for x in ww],
+                        "sigma_hat": [
+                            float(x) for x in np.atleast_1d(
+                                self.controller.last_sigma
+                            )
+                        ],
+                        "obs": [
+                            float(x) for x in np.atleast_1d(
+                                self.controller.last_state
+                            )
+                        ],
+                    },
+                )
+            return w, ww
 
     # -------------------------------------------------------------- tracing
     def _trace_step(self, epoch, step, t_compute, stall, rebuild_stall,
@@ -414,25 +433,9 @@ class TrainerWorker:
         t0 = self.meter.wall_s
         gstep = epoch * self.cfg.steps_per_epoch + step
         if self.engine is not None and self.engine.step_edges:
-            # per-edge flop/byte estimate of the measured SAGE step; on an
-            # accelerator also priced at that chip's peak rates
-            # (order-of-magnitude attribution, not a fitted law —
-            # calibration.calibrate_compute owns the fitted one)
-            n_edges = int(self.engine.step_edges[-1])
-            width = float(self.engine.mcfg.d_in + self.engine.mcfg.d_hidden)
-            flops = 2.0 * n_edges * width
-            nbyte = 4.0 * n_edges * width
-            args = {"n_edges": n_edges, "flops_est": flops,
-                    "bytes_est": nbyte,
+            args = {"n_edges": int(self.engine.step_edges[-1]),
                     "prep_s": self.engine.prep_s[-1],
                     "step_s": self.engine.step_s[-1]}
-            peaks = self.engine.peaks
-            if peaks is not None:
-                comp_s, mem_s = flops / peaks.flops, nbyte / peaks.hbm_bw
-                args.update(
-                    roof_compute_s=comp_s, roof_memory_s=mem_s,
-                    bound="memory" if mem_s >= comp_s else "compute",
-                )
             self.tracer.span(
                 "compute", "measured", t0, t0 + t_compute, step=gstep,
                 epoch=epoch, args=args,
@@ -474,6 +477,7 @@ class TrainerWorker:
         from repro.train import gnn_trainer as gt
 
         cfg = self.cfg
+        self.spans.at(epoch, -1)
         if self.fabric is not None:
             # fabric path: delta/sigma are time-varying within the epoch;
             # refreshed per step, epoch log gets the step mean
@@ -493,66 +497,71 @@ class TrainerWorker:
         trace = self.traces[epoch]
 
         if cfg.method == "rapidgnn" and self.cache is not None:
-            # epoch-level rebuild from the full presampled epoch trace
-            remote = [self.store.remote_ids_of(t) for t in trace]
-            plan = self.cache.plan_window(remote, self.weights)
-            raw, cpu_rb, nbytes, nrpc, _ = self._net_bulk(
-                plan.per_owner_fetched.astype(np.float64), self.delta
-            )
-            if self.tiered:
-                self.store.pin_window(plan.hot_nodes)
-                charge = self.store.touch(plan.hot_nodes[plan.fetched])
-                if charge is not None and not charge.empty:
-                    if charge.per_owner_rows.any():
-                        braw, bcpu, bb, br, _ = self._net_bulk(
-                            charge.per_owner_rows, self.delta
-                        )
-                        raw += braw
-                        cpu_rb += bcpu
-                        nbytes += bb
-                        nrpc += br
-                    if charge.local_rows:
-                        t_local = (
-                            charge.local_rows * self.bytes_per_row
-                            * float(self.params.beta)
-                            * float(self.mem_budget.host_read_factor)
-                        )
-                        raw += t_local
-                        cpu_rb += t_local
-            if self.device is not None:
-                self.device.load(plan, self.store.peek_rows)
-            if self.tracer.enabled:
-                # same charge laws, same emission order as the two meter
-                # calls below (ledger order == meter order)
-                t0 = self.meter.wall_s
-                self.tracer.charge_background(
-                    t0, cpu_rb, component="epoch-cache", name="epoch-rebuild",
-                    epoch=epoch,
-                    args={"bytes": float(nbytes), "rpcs": int(nrpc),
-                          "fetch_s": float(raw),
-                          "rows": float(plan.per_owner_fetched.sum())},
-                )
-                self.tracer.charge_step(
-                    t0,
-                    StepSample(0.0, float(self.params.alpha_crit) * raw, 0.0),
-                    component="epoch-cache", name="leak", epoch=epoch,
-                )
-                self._trace_tier_counters(t0, 0, epoch)
-            self.meter.record_background(cpu_rb, nbytes, nrpc)
-            self.meter.record_step(
-                StepSample(0.0, float(self.params.alpha_crit) * raw, 0.0)
-            )
-            self.cache.swap(plan)
-            self.fetched_rows_by_owner += plan.per_owner_fetched
+            with self.spans.span("worker.rebuild"):
+                self._rebuild_epoch(epoch, trace)
 
         if self.prefetcher is not None:
             # Stage-3: resolve this epoch's batch payloads up to Q ahead
             self.prefetcher.schedule(list(trace))
 
+    def _rebuild_epoch(self, epoch: int, trace) -> None:
+        """rapidgnn: one rebuild per epoch from the full presampled trace."""
+        remote = [self.store.remote_ids_of(t) for t in trace]
+        plan = self.cache.plan_window(remote, self.weights)
+        raw, cpu_rb, nbytes, nrpc, _ = self._net_bulk(
+            plan.per_owner_fetched.astype(np.float64), self.delta
+        )
+        if self.tiered:
+            self.store.pin_window(plan.hot_nodes)
+            charge = self._touch(plan.hot_nodes[plan.fetched])
+            if charge is not None and not charge.empty:
+                if charge.per_owner_rows.any():
+                    braw, bcpu, bb, br, _ = self._net_bulk(
+                        charge.per_owner_rows, self.delta
+                    )
+                    raw += braw
+                    cpu_rb += bcpu
+                    nbytes += bb
+                    nrpc += br
+                if charge.local_rows:
+                    t_local = (
+                        charge.local_rows * self.bytes_per_row
+                        * float(self.params.beta)
+                        * float(self.mem_budget.host_read_factor)
+                    )
+                    raw += t_local
+                    cpu_rb += t_local
+        if self.device is not None:
+            self.device.load(plan, self.store.peek_rows)
+        if self.tracer.enabled:
+            # same charge laws, same emission order as the two meter
+            # calls below (ledger order == meter order)
+            t0 = self.meter.wall_s
+            self.tracer.charge_background(
+                t0, cpu_rb, component="epoch-cache", name="epoch-rebuild",
+                epoch=epoch,
+                args={"bytes": float(nbytes), "rpcs": int(nrpc),
+                      "fetch_s": float(raw),
+                      "rows": float(plan.per_owner_fetched.sum())},
+            )
+            self.tracer.charge_step(
+                t0,
+                StepSample(0.0, float(self.params.alpha_crit) * raw, 0.0),
+                component="epoch-cache", name="leak", epoch=epoch,
+            )
+            self._trace_tier_counters(t0, 0, epoch)
+        self.meter.record_background(cpu_rb, nbytes, nrpc)
+        self.meter.record_step(
+            StepSample(0.0, float(self.params.alpha_crit) * raw, 0.0)
+        )
+        self.cache.swap(plan)
+        self.fetched_rows_by_owner += plan.per_owner_fetched
+
     def end_epoch(self, epoch: int) -> None:
         from repro.train import gnn_trainer as gt
 
         cfg = self.cfg
+        self.spans.at(epoch, -1)
         self.meter.mark_epoch()
         if self.fabric is not None:
             self.sigma_log.append(
@@ -565,9 +574,13 @@ class TrainerWorker:
         )
         self.wall_log.append(self.meter.wall_s - self._wall0)
         if cfg.run_model and self.model_state is not None:
-            self.acc_log.append(gt._model_eval(self.model_state, self.graph))
+            with self.spans.span("worker.eval"):
+                acc = gt._model_eval(self.model_state, self.graph)
+            self.acc_log.append(acc)
         elif cfg.run_model and self.engine is not None:
-            self.acc_log.append(self.engine.model_eval(self.graph))
+            with self.spans.span("worker.eval"):
+                acc = self.engine.model_eval(self.graph)
+            self.acc_log.append(acc)
         if self.controller is not None and epoch == cfg.warmup_epochs - 1:
             self.controller.observe_warmup()
         if epoch == cfg.warmup_epochs - 1:
@@ -577,6 +590,11 @@ class TrainerWorker:
 
     # ------------------------------------------------------------------- step
     def step(self, epoch: int, step: int) -> None:
+        self.spans.at(epoch, step)
+        with self.spans.span("worker.step"):
+            self._step(epoch, step)
+
+    def _step(self, epoch: int, step: int) -> None:
         from repro.train import gnn_trainer as gt
 
         cfg = self.cfg
@@ -598,42 +616,44 @@ class TrainerWorker:
             adaptive_now = (
                 self.controller is not None and epoch >= cfg.warmup_epochs
             )
-            if not self.use_async:
-                self._rebuild_sync(adaptive_now, epoch, step, delta)
-            else:
-                self._rebuild_async(adaptive_now, epoch, step, delta)
+            with self.spans.span("worker.rebuild"):
+                if not self.use_async:
+                    self._rebuild_sync(adaptive_now, epoch, step, delta)
+                else:
+                    self._rebuild_async(adaptive_now, epoch, step, delta)
             self.window_left = self.window
         self.epoch_windows.append(self.window)
 
         # ---- resolve features ----
-        if self.prefetcher is not None:
-            # real payload gather, resolved ahead by the Stage-3 queue
-            # (timings land in the PipelineReport; classification below
-            # stays synchronous so the hit/miss stream is unperturbed)
-            self.prefetcher.get()
-        if self.cache is not None:
-            # one searchsorted probe recorded into both stat sinks
-            miss_ids = self.cache.access(
-                remote_ids, self.epoch_stats, self.window_stats
-            )
-        else:
-            miss_ids = remote_ids
-        self.step_hits.append(len(remote_ids) - len(miss_ids))
-        self.step_misses.append(len(miss_ids))
-        per_owner = np.zeros(self.n_owners, np.float64)
-        if len(miss_ids):
-            oi = self.owner_idx_map[miss_ids]
-            per_owner += np.bincount(oi, minlength=self.n_owners)
-            self.fetched_rows_by_owner += per_owner
+        with self.spans.span("worker.features"):
+            if self.prefetcher is not None:
+                # real payload gather, resolved ahead by the Stage-3 queue
+                # (timings land in the PipelineReport; classification below
+                # stays synchronous so the hit/miss stream is unperturbed)
+                self.prefetcher.get()
+            if self.cache is not None:
+                # one searchsorted probe recorded into both stat sinks
+                miss_ids = self.cache.access(
+                    remote_ids, self.epoch_stats, self.window_stats
+                )
+            else:
+                miss_ids = remote_ids
+            self.step_hits.append(len(remote_ids) - len(miss_ids))
+            self.step_misses.append(len(miss_ids))
+            per_owner = np.zeros(self.n_owners, np.float64)
+            if len(miss_ids):
+                oi = self.owner_idx_map[miss_ids]
+                per_owner += np.bincount(oi, minlength=self.n_owners)
+                self.fetched_rows_by_owner += per_owner
 
-        device_rows = None
-        if self.device is not None and len(remote_ids):
-            # hit path: real payload rows gathered from the device tier
-            # through the embedding_bag kernel (pure compute; timings and
-            # the hit/miss stream above are untouched)
-            hit_mask, _rows = self.device.gather(remote_ids)
-            self.store.tier_stats.device_hits += int(hit_mask.sum())
-            device_rows = (hit_mask, _rows)
+            device_rows = None
+            if self.device is not None and len(remote_ids):
+                # hit path: real payload rows gathered from the device tier
+                # through the embedding_bag kernel (pure compute; timings and
+                # the hit/miss stream above are untouched)
+                hit_mask, _rows = self.device.gather(remote_ids)
+                self.store.tier_stats.device_hits += int(hit_mask.sum())
+                device_rows = (hit_mask, _rows)
 
         # ---- host tier: stage this step's working set -------------------
         # Blocks are touched for the rows the step actually reads from host
@@ -647,7 +667,7 @@ class TrainerWorker:
             local_ids = input_nodes[
                 self.owner[np.asarray(input_nodes)] == self.rank
             ]
-            charge = self.store.touch(np.concatenate(
+            charge = self._touch(np.concatenate(
                 [np.asarray(local_ids, np.int64),
                  np.asarray(miss_ids, np.int64)]
             ))
@@ -714,8 +734,9 @@ class TrainerWorker:
             # included, is charged where the modeled lane charges the
             # t_base constant
             mb = self.mbs[epoch][step]
-            x_in = self._resolve_features(input_nodes, remote_ids,
-                                          device_rows)
+            with self.spans.span("worker.resolve"):
+                x_in = self._resolve_features(input_nodes, remote_ids,
+                                              device_rows)
             t_compute = self.engine.step(mb, x_in)
         else:
             t_compute = self.t_base
@@ -808,7 +829,7 @@ class TrainerWorker:
             # its own prefetch), then stage them and charge the traffic to
             # the rebuild's background/leak path
             self.store.pin_window(plan.hot_nodes)
-            charge = self.store.touch(plan.hot_nodes[plan.fetched])
+            charge = self._touch(plan.hot_nodes[plan.fetched])
             if charge is not None and not charge.empty:
                 if charge.per_owner_rows.any():
                     braw, bcpu, bb, br, _ = self._net_bulk(
@@ -888,7 +909,7 @@ class TrainerWorker:
             # builder's fetch itself goes through the pure peek_rows):
             # re-pin to the new plan, then stage its fetch rows
             self.store.pin_window(plan.hot_nodes)
-            charge = self.store.touch(plan.hot_nodes[plan.fetched])
+            charge = self._touch(plan.hot_nodes[plan.fetched])
             if charge is not None and not charge.empty:
                 if charge.per_owner_rows.any():
                     _, blk_cpu, blk_bytes, blk_rpcs, _ = self._net_bulk(

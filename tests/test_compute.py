@@ -117,13 +117,16 @@ class TestDevicePlumbing:
             device_peaks("TPU v99")
 
     def test_cpu_engine_prices_nothing(self):
+        """On the CPU the engine takes the XLA block path; it holds no
+        roofline peaks, and its transfer counters start at zero."""
         from repro.train import gnn_trainer as gt
         from repro.train.compute import ComputeEngine
 
         cfg = gt.RunConfig(**_PIN_CFG)
         graph = gt.datasets.materialize(cfg.dataset, seed=0)
         eng = ComputeEngine(graph, cfg)
-        assert eng.peaks is None and eng.agg_impl == "xla"
+        assert not hasattr(eng, "peaks") and eng.agg_impl == "xla"
+        assert (eng.h2d_bytes, eng.tiles, eng.pad_tiles) == (0, 0, 0)
 
     def test_prepare_uploads_canonical_layers(self):
         from repro.train import gnn_trainer as gt
@@ -168,6 +171,45 @@ class TestDevicePlumbing:
         assert rep["prep_s"] == pytest.approx([2e-3] * n_steps)
         assert charged == pytest.approx([3e-3] * n_steps)
         assert rep["n_compiles"] == 1
+
+    def test_step_counts_its_upload_exactly(self, monkeypatch):
+        """``h2d_bytes`` is the summed ``nbytes`` of what ``_prepare`` and
+        ``pad_input`` return; ``tiles`` the layers' ``rows`` lengths after
+        padding, ``pad_tiles`` the difference from their lengths before."""
+        import jax
+
+        from repro.kernels import segment_mm
+        from repro.train import gnn_trainer as gt
+        from repro.train.compute import ComputeEngine
+
+        cfg = gt.RunConfig(**dict(_PIN_CFG, n_epochs=1, steps_per_epoch=2))
+        graph, _owner, _traces, mbs = gt.build_trace(cfg)
+        eng = ComputeEngine(graph, cfg)
+        eng.parity_max_diff = float("nan")
+        real = segment_mm.to_block_sparse
+        unpadded = []
+
+        def spy(*a, **k):
+            out = real(*a, **k)
+            unpadded.append(len(out[0]))
+            return out
+
+        monkeypatch.setattr(segment_mm, "to_block_sparse", spy)
+        want_bytes = want_tiles = 0
+        for mb in mbs[0]:
+            x_in = np.asarray(graph.features[mb.input_nodes], np.float32)
+            host, x_rows, _, _ = eng._prepare(mb)
+            x_pad = eng.pad_input(x_in, x_rows)
+            want_bytes += sum(a.nbytes for a in
+                              jax.tree.leaves((host, x_pad)))
+            want_tiles += sum(len(layer["rows"]) for layer in host)
+            eng.step(mb, x_in)
+        # _prepare ran twice per batch: once here, once inside step
+        n_before = sum(unpadded) // 2
+        assert eng.h2d_bytes == want_bytes
+        assert eng.tiles == want_tiles
+        assert eng.pad_tiles == want_tiles - n_before
+        assert eng.pad_tiles > 0
 
     @pytest.mark.parametrize("agg_impl", ["xla", "pallas"])
     def test_step_lowers_every_dot_at_model_precision(self, agg_impl):

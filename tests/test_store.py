@@ -255,6 +255,22 @@ class TestDevicePayloadTier:
         tier, _, _ = self._loaded_tier()
         assert tier.gather_slots(np.empty(0, np.int64)).shape == (0, 6)
 
+    def test_transfer_counters_exact(self):
+        """One table upload per load (capacity x width float32, on the
+        first gather); each gather copies back its pow2 bucket of rows."""
+        tier, cache, table = self._loaded_tier(capacity=32, d=6)
+        assert (tier.h2d_bytes, tier.d2h_bytes) == (0, 0)
+        tier.gather_slots(np.arange(3))       # bucket 4
+        tier.gather_slots(np.arange(9))       # bucket 16
+        assert tier.h2d_bytes == 32 * 6 * 4
+        assert tier.d2h_bytes == (4 + 16) * 6 * 4
+        plan = cache.plan_window([cache.active_nodes[:5]], np.ones(1))
+        tier.load(plan, peek_fn=lambda ids: table[np.asarray(ids)])
+        cache.swap(plan)
+        tier.gather_slots(np.arange(2))       # re-uploads the table
+        assert tier.h2d_bytes == 2 * 32 * 6 * 4
+        assert tier.d2h_bytes == (4 + 16 + 2) * 6 * 4
+
     def test_load_persists_rows_across_swap(self):
         tier, cache, table = self._loaded_tier()
         # second window overlapping the first: persisted rows must be
