@@ -12,14 +12,21 @@ resolved, with neighborhood aggregation dispatched through the
     format executes through the compiled XLA twin (``block_spmm_xla``),
     so the measured numbers are real compiled-step times everywhere.
 
-The edge-list -> block conversion is numpy preprocessing, run and
-uploaded afresh for every step and dropped after it: nothing keeps a batch
-on the device between steps (at reddit width one batch's layer-0 tiles
-alone take ~4.3 GB). That host work is timed as its own span, ``prep_s``,
-apart from the compiled step, ``step_s``; the meter is charged both.
-Dynamic block/src/dst counts are bucketed to powers of two so the jitted
-step compiles once per size bucket; compilation happens ahead-of-time
-(``.lower().compile()``) and is excluded from the measured step time.
+The edge-list -> block conversion is split between host and device.
+The host computes each layer's index plan (``segment_mm.block_sparse_plan``:
+the tile ids, and each edge's tile slot and in-tile offset) and uploads
+it with the padded input; the compiled step scatters the tiles from the
+plan on the device (``segment_mm.tiles_from_plan``) before each layer's
+aggregation. A plan is a few bytes per edge where the tiles are 64 KiB
+each (at reddit width one batch's layer-0 tiles take ~4.3 GB, for ~10
+nonzeros a tile). Nothing keeps a batch on the device between steps. The
+host work and upload are timed as their own span, ``prep_s``, apart from
+the compiled step, ``step_s``, which includes the tile scatter; the meter
+is charged both. Dynamic tile/src/dst counts are bucketed to powers of
+two, and each plan is padded to the dst bucket times the layer's
+fan-out, so the jitted step compiles once per size bucket; compilation
+happens ahead-of-time (``.lower().compile()``) and is excluded from the
+measured step time.
 
 The block path is parity-asserted against the ``models/gnn/common``
 scatter reference (``check_parity``, run automatically on the first
@@ -27,10 +34,11 @@ step). The step's phases are host-clock spans of the worker's
 ``repro.obs.wall`` recorder (``engine.build``, ``engine.pad``,
 ``engine.upload``, ``engine.parity``, ``engine.compile``, ``engine.run``,
 ``engine.free`` inside ``engine.step``), and its uploads are counted exactly:
-``h2d_bytes``, and the 128x128 ``tiles`` uploaded of which ``pad_tiles``
-are power-of-two padding. Gradient sync flows through ``grad_compression``
-with error feedback; ``sync_wire_bytes`` is what the cluster driver feeds
-into ``ring_collective_cost`` in place of the uncompressed payload.
+``h2d_bytes``, the 128x128 ``tiles`` built of which ``pad_tiles`` are
+power-of-two padding, and the real ``edges`` scattered into them.
+Gradient sync flows through ``grad_compression`` with error feedback;
+``sync_wire_bytes`` is what the cluster driver feeds into
+``ring_collective_cost`` in place of the uncompressed payload.
 """
 from __future__ import annotations
 
@@ -146,16 +154,15 @@ class ComputeEngine:
         self.compile_s = 0.0
         self.n_compiles = 0
         self.h2d_bytes = 0       # everything step uploads
-        self.tiles = 0           # 128x128 tiles uploaded, all layers
+        self.tiles = 0           # 128x128 tiles built, all layers
         self.pad_tiles = 0       # of which power-of-two padding
         self.parity_max_diff: float | None = None
         self._parity_tol = 2e-3
 
     # ------------------------------------------------------------ prepare
     def prepare(self, mb):
-        """Block-sparse conversion + pow2 bucketing for one mini-batch,
-        uploaded: ``(layers, x_rows, n_edges)`` with ``layers`` on the
-        device."""
+        """Block-sparse plan + pow2 bucketing for one mini-batch, uploaded:
+        ``(layers, x_rows, n_edges)`` with ``layers`` on the device."""
         import jax
 
         host, x_rows, n_edges, _ = self._prepare(mb)
@@ -163,8 +170,17 @@ class ComputeEngine:
 
     def _prepare(self, mb):
         """``prepare`` on the host: the layers as numpy arrays, and how many
-        of their tiles are power-of-two padding."""
-        from repro.kernels.segment_mm import to_block_sparse
+        of their tiles are power-of-two padding.
+
+        Each layer holds its tiles' ``rows``/``cols`` (padded to a power of
+        two with zero tiles on the last row-block) and its real edges'
+        ``slot``/``off`` (``segment_mm.sorted_edge_slots``), padded to the
+        dst bucket times the layer's largest in-degree (its fan-out) with
+        slots the device build scatters as zeros; masked edges count toward
+        the tile set but are not scattered (their weight is 0)."""
+        from repro.kernels.segment_mm import (
+            block_sparse_plan, sorted_edge_slots,
+        )
 
         t = self.tile
         layers = []
@@ -176,34 +192,35 @@ class ComputeEngine:
             n_dst_true = len(blk.dst_nodes)
             n_dst_blocks = _bucket(-(-n_dst_true // t))
             n_dst_pad = n_dst_blocks * t
-            w = blk.edge_mask.astype(np.float32)
-            rows, cols, blocks, ndb, n_src_pad = to_block_sparse(
-                blk.edge_src, blk.edge_dst, n_dst_pad, src_rows, t, t, w
+            rows, cols, slot, off, ndb, n_src_pad = block_sparse_plan(
+                blk.edge_src, blk.edge_dst, n_dst_pad, src_rows, t, t
             )
             assert n_src_pad == src_rows and ndb == n_dst_blocks
             nbp = _bucket(len(rows))
             if nbp > len(rows):
                 pad = nbp - len(rows)
                 pad_tiles += pad
-                # padding blocks stay zero and point at the last row-block
+                # padding tiles stay zero and point at the last row-block
                 # (rows stay sorted; they accumulate nothing)
                 rows = np.concatenate(
                     [rows, np.full(pad, ndb - 1, np.int32)]
                 )
                 cols = np.concatenate([cols, np.zeros(pad, np.int32)])
-                blocks = np.concatenate(
-                    [blocks, np.zeros((pad, t, t), np.float32)]
-                )
             indeg = np.bincount(
                 blk.edge_dst[blk.edge_mask], minlength=n_dst_pad
-            ).astype(np.float32)
+            )
+            slot, off = sorted_edge_slots(
+                slot, off, blk.edge_mask, n_dst_pad * int(indeg.max()),
+                nbp, t * t,
+            )
             dst_pos = np.zeros(n_dst_pad, np.int32)
             dst_pos[:n_dst_true] = blk.dst_pos
             layer = {
                 "rows": rows,
                 "cols": cols,
-                "blocks": blocks,
-                "counts": np.maximum(indeg, 1.0)[:, None],
+                "slot": slot,
+                "off": off,
+                "counts": np.maximum(indeg, 1).astype(np.float32)[:, None],
                 "dst_pos": dst_pos,
             }
             if i == len(mb.blocks) - 1:
@@ -224,6 +241,14 @@ class ComputeEngine:
         return x
 
     # ------------------------------------------------------------ forward
+    def _tiles(self, layer):
+        """The layer's adjacency tiles, scattered on the device from its
+        plan."""
+        from repro.kernels.segment_mm import tiles_from_plan
+
+        return tiles_from_plan(layer["slot"], layer["off"],
+                               layer["rows"].shape[0], self.tile, self.tile)
+
     def _aggregate(self, layer, h):
         import jax.numpy as jnp
 
@@ -232,6 +257,7 @@ class ComputeEngine:
 
         t = self.tile
         n_dst_blocks = layer["counts"].shape[0] // t
+        blocks = self._tiles(layer)
         f = h.shape[1]
         if self.agg_impl == "pallas":
             f_pad = -(-f // t) * t
@@ -239,19 +265,20 @@ class ComputeEngine:
             if f_pad != f:
                 hp = jnp.zeros((h.shape[0], f_pad), h.dtype).at[:, :f].set(h)
             y = block_spmm_kernel(
-                layer["rows"], layer["cols"], layer["blocks"], hp,
+                layer["rows"], layer["cols"], blocks, hp,
                 n_dst_blocks, tn=t, tm=t, tf=t,
             )[:, :f]
         else:
             y = block_spmm_xla(
-                layer["rows"], layer["cols"], layer["blocks"], h,
+                layer["rows"], layer["cols"], blocks, h,
                 n_dst_blocks, tn=t, tm=t,
             )
         return y / layer["counts"]
 
     def _forward(self, params, x_pad, layers):
         """Block-path SAGE forward over prepared layers (padded rows), at
-        ``MATMUL_PRECISION``."""
+        ``MATMUL_PRECISION``; each layer's tiles are built from its plan
+        before its aggregation."""
         import jax
 
         h = x_pad
@@ -295,14 +322,15 @@ class ComputeEngine:
         """One measured forward/backward/optimizer step.
 
         ``x_in`` are the resolved feature rows for ``mb.input_nodes``.
-        Two spans are timed: ``prep_s``, the host build of the block
-        layers and padded input, their upload (waited for) and the free of
-        the host copies; and ``step_s``, the compiled step alone, which is
-        what ``calibration_samples`` fits. AOT compilation on a new shape
-        bucket and the first step's parity check are in neither
-        (compilation is accounted in ``compile_s``). Returns the sum of
-        the two spans, the step's compute time as the meter charges it.
-        ``h2d_bytes``, ``tiles`` and ``pad_tiles`` count the upload (the
+        Two spans are timed: ``prep_s``, the host build of the layers'
+        plans and the padded input, their upload (waited for) and the free
+        of the host copies; and ``step_s``, the compiled step alone (the
+        tile scatter included), which is what ``calibration_samples``
+        fits. AOT compilation on a new shape bucket and the first step's
+        parity check are in neither (compilation is accounted in
+        ``compile_s``). Returns the sum of the two spans, the step's
+        compute time as the meter charges it. ``h2d_bytes`` counts the
+        upload, ``tiles`` and ``pad_tiles`` the tiles the step builds (the
         first step's parity check uploads its own copies, not counted).
         """
         import jax
@@ -320,7 +348,7 @@ class ComputeEngine:
             self.tiles += tiles
             self.pad_tiles += pad_tiles
             with spans.span("engine.upload", h2d_bytes=nbytes, tiles=tiles,
-                            pad_tiles=pad_tiles):
+                            pad_tiles=pad_tiles, edges=n_edges):
                 layers, x_dev = jax.block_until_ready(
                     jax.device_put((host, x_pad)))
             prep = self.clock() - t
@@ -329,7 +357,8 @@ class ComputeEngine:
                     self.check_parity(mb, x_in, _prep=(layers, x_rows))
             args = (self.params, self.opt_state, self.error, x_dev, layers)
             sig = (x_pad.shape,) + tuple(
-                (l["rows"].shape[0], l["counts"].shape[0]) for l in layers
+                (l["rows"].shape[0], l["counts"].shape[0], l["slot"].shape[0])
+                for l in layers
             )
             if sig not in self._exec:
                 with spans.span("engine.compile"):
@@ -343,8 +372,8 @@ class ComputeEngine:
                 jax.block_until_ready(out)
                 dt = self.clock() - t0
             # the host copies the upload read are held past the timed step
-            # and dropped here, so their free (~4.4 GB at reddit width) is
-            # charged to prep_s and not to the compiled step
+            # and dropped here, so their free (~0.3 GB at reddit width, the
+            # padded input) is charged to prep_s and not to the compiled step
             with spans.span("engine.free"):
                 t = self.clock()
                 del host, x_pad

@@ -134,12 +134,29 @@ class TestDevicePlumbing:
 
         cfg = gt.RunConfig(**dict(_PIN_CFG, n_epochs=1, steps_per_epoch=1))
         graph, _owner, _traces, mbs = gt.build_trace(cfg)
-        layers, x_rows, n_edges = ComputeEngine(graph, cfg).prepare(mbs[0][0])
+        mb = mbs[0][0]
+        layers, x_rows, n_edges = ComputeEngine(graph, cfg).prepare(mb)
         assert x_rows % 128 == 0 and n_edges > 0
-        for layer in layers:
+        scattered = 0
+        # blocks are input layer first; fan-outs are listed output first
+        for layer, fanout in zip(layers, cfg.fanouts[::-1]):
             assert all(isinstance(a, jax.Array) for a in layer.values())
-            assert layer["rows"].dtype == jnp.int32
-            assert layer["blocks"].dtype == jnp.float32
+            assert "blocks" not in layer
+            for key in ("rows", "cols", "slot", "off", "dst_pos"):
+                assert layer[key].dtype == jnp.int32
+            assert layer["counts"].dtype == jnp.float32
+            # one slot per sampled edge of every padded dst row; the real
+            # edges first, sorted, then padding (slot = tile count)
+            n_tiles = layer["rows"].shape[0]
+            slot = np.asarray(layer["slot"])
+            assert len(slot) == layer["counts"].shape[0] * fanout
+            real = slot < n_tiles
+            scattered += int(real.sum())
+            assert not real[real.sum():].any()
+            key = slot[real].astype(np.int64) * 128**2 + np.asarray(
+                layer["off"])[real]
+            assert (np.diff(key) >= 0).all() and key.max() < n_tiles * 128**2
+        assert scattered == n_edges
         assert layers[-1]["labels"].dtype == jnp.int32
 
     @pytest.mark.parametrize("n_steps", [1, 3])
@@ -173,9 +190,10 @@ class TestDevicePlumbing:
         assert rep["n_compiles"] == 1
 
     def test_step_counts_its_upload_exactly(self, monkeypatch):
-        """``h2d_bytes`` is the summed ``nbytes`` of what ``_prepare`` and
-        ``pad_input`` return; ``tiles`` the layers' ``rows`` lengths after
-        padding, ``pad_tiles`` the difference from their lengths before."""
+        """``h2d_bytes`` is the summed ``nbytes`` of the layers' plans
+        ``_prepare`` returns and of ``pad_input``'s padded input; ``tiles``
+        the layers' ``rows`` lengths after padding, ``pad_tiles`` the
+        difference from the plan's lengths before."""
         import jax
 
         from repro.kernels import segment_mm
@@ -186,7 +204,7 @@ class TestDevicePlumbing:
         graph, _owner, _traces, mbs = gt.build_trace(cfg)
         eng = ComputeEngine(graph, cfg)
         eng.parity_max_diff = float("nan")
-        real = segment_mm.to_block_sparse
+        real = segment_mm.block_sparse_plan
         unpadded = []
 
         def spy(*a, **k):
@@ -194,11 +212,12 @@ class TestDevicePlumbing:
             unpadded.append(len(out[0]))
             return out
 
-        monkeypatch.setattr(segment_mm, "to_block_sparse", spy)
+        monkeypatch.setattr(segment_mm, "block_sparse_plan", spy)
         want_bytes = want_tiles = 0
         for mb in mbs[0]:
             x_in = np.asarray(graph.features[mb.input_nodes], np.float32)
             host, x_rows, _, _ = eng._prepare(mb)
+            assert all("blocks" not in layer for layer in host)
             x_pad = eng.pad_input(x_in, x_rows)
             want_bytes += sum(a.nbytes for a in
                               jax.tree.leaves((host, x_pad)))
@@ -210,6 +229,51 @@ class TestDevicePlumbing:
         assert eng.tiles == want_tiles
         assert eng.pad_tiles == want_tiles - n_before
         assert eng.pad_tiles > 0
+
+    def test_device_tiles_train_as_host_tiles(self):
+        """Over three steps the engine, which scatters its tiles on the
+        device, gives bit for bit the losses and parameters of the same
+        step fed ``to_block_sparse``'s host tiles, and compiles once per
+        (input rows, tile bucket, dst bucket) signature, as host tiles
+        did."""
+        from repro.train import gnn_trainer as gt
+        from repro.train.compute import ComputeEngine
+
+        class HostTiles(ComputeEngine):
+            def _prepare(self, mb):
+                layers, x_rows, n_edges, pad_tiles = super()._prepare(mb)
+                src_rows = x_rows
+                for layer, blk in zip(layers, mb.blocks):
+                    n_dst_pad = len(layer["counts"])
+                    _, _, blocks, _, _ = to_block_sparse(
+                        blk.edge_src, blk.edge_dst, n_dst_pad, src_rows,
+                        edge_weight=blk.edge_mask.astype(np.float32),
+                    )
+                    layer["blocks"] = np.zeros(
+                        (len(layer["rows"]), 128, 128), np.float32)
+                    layer["blocks"][: len(blocks)] = blocks
+                    src_rows = n_dst_pad
+                return layers, x_rows, n_edges, pad_tiles
+
+            def _tiles(self, layer):
+                return layer["blocks"]
+
+        cfg = gt.RunConfig(**dict(_PIN_CFG, n_epochs=1, steps_per_epoch=3))
+        graph, _owner, _traces, mbs = gt.build_trace(cfg)
+        device, host = ComputeEngine(graph, cfg), HostTiles(graph, cfg)
+        sigs = set()
+        for mb in mbs[0]:
+            x_in = np.asarray(graph.features[mb.input_nodes], np.float32)
+            layers, x_rows, _, _ = device._prepare(mb)
+            sigs.add((x_rows,) + tuple(
+                (len(l["rows"]), len(l["counts"])) for l in layers))
+            device.step(mb, x_in)
+            host.step(mb, x_in)
+        assert device.losses == host.losses
+        for a, b in zip(jax.tree.leaves(device.params),
+                        jax.tree.leaves(host.params)):
+            assert np.array_equal(np.asarray(a), np.asarray(b))
+        assert device.n_compiles == host.n_compiles == len(sigs)
 
     @pytest.mark.parametrize("agg_impl", ["xla", "pallas"])
     def test_step_lowers_every_dot_at_model_precision(self, agg_impl):

@@ -10,7 +10,8 @@ from repro.kernels.flash_attention import flash_attention
 from repro.kernels.flash_attention.kernel import flash_attention_kernel
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.segment_mm import (
-    block_spmm_xla, segment_mm, to_block_sparse,
+    block_sparse_plan, block_spmm_xla, segment_mm, sorted_edge_slots,
+    tiles_from_plan, to_block_sparse,
 )
 from repro.kernels.segment_mm.kernel import block_spmm_kernel
 from repro.kernels.segment_mm.ref import spmm_ref
@@ -102,6 +103,53 @@ class TestSegmentMM:
         assert (np.diff(rows) >= 0).all()  # row-sorted
         total = blocks.sum()
         assert total == 100  # one unit per edge
+
+    @pytest.mark.parametrize("n_src,n_dst,dst_hi,n_edges,t,masked,pad", [
+        (300, 260, 260, 2000, 32, 0.0, 0),     # dense-ish, no padding edge
+        (64, 700, 700, 300, 32, 0.3, 50),      # many empty dst blocks
+        (1000, 50, 50, 4000, 8, 0.5, 1000),    # many-to-few, small tiles
+        (512, 1024, 200, 600, 128, 0.2, 100),  # upper dst blocks all empty
+        (256, 256, 256, 100, 128, 1.0, 10),    # every edge masked
+        (128, 128, 128, 0, 128, 0.0, 5),       # no edges at all
+    ])
+    def test_device_tiles_equal_host_tiles(self, n_src, n_dst, dst_hi,
+                                           n_edges, t, masked, pad):
+        """Tiles scattered on the device from the sorted edge slots equal
+        ``to_block_sparse``'s 0/1-weighted tiles bit for bit, with zero
+        tiles up to the power-of-two tile count; duplicate edges add up,
+        masked edges keep their tile but add nothing, and padding edges
+        (slot = tile count) add nothing."""
+        rng = np.random.default_rng(n_src + n_edges + t)
+        src = rng.integers(0, n_src, n_edges)
+        dst = rng.integers(0, dst_hi, n_edges)
+        dup = rng.integers(0, max(n_edges, 1), n_edges // 4)
+        src = np.concatenate([src, src[dup]])
+        dst = np.concatenate([dst, dst[dup]])
+        keep = rng.random(len(src)) >= masked
+        rows, cols, blocks, ndb, _ = to_block_sparse(
+            src, dst, n_dst, n_src, t, t, keep.astype(np.float32)
+        )
+        p_rows, p_cols, slot, off, p_ndb, _ = block_sparse_plan(
+            src, dst, n_dst, n_src, t, t
+        )
+        assert np.array_equal(p_rows, rows) and np.array_equal(p_cols, cols)
+        assert p_ndb == ndb
+        nbp = 1 << (len(rows) - 1).bit_length()
+        slot, off = sorted_edge_slots(slot, off, keep, int(keep.sum()) + pad,
+                                      nbp, t * t)
+        assert slot.dtype == off.dtype == np.int32
+        assert (slot[keep.sum():] == nbp).all()
+        # every index the scatter gets, padding included, is in bounds and
+        # in sorted order: a dropped out-of-range index lost updates on TPU
+        flat = np.minimum(slot, nbp - 1).astype(np.int64) * t * t + off
+        assert (np.diff(flat) >= 0).all() and flat[-1] < nbp * t * t
+        build = jax.jit(tiles_from_plan, static_argnums=(2, 3, 4))
+        got = np.asarray(build(jnp.asarray(slot), jnp.asarray(off), nbp,
+                               t, t))
+        want = np.zeros((nbp, t, t), np.float32)
+        want[: len(rows)] = blocks
+        assert np.array_equal(got, want)
+        assert got.sum() == keep.sum()
 
 
 class TestFlashAttention:

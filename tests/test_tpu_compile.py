@@ -17,6 +17,7 @@ from jax.sharding import SingleDeviceSharding
 # shapes of one reddit-width batch after pow2 bucketing (train/compute.py)
 TILES_L0, SRC_ROWS_L0, DST_ROWS_L0 = 65_536, 131_072, 32_768
 TILES_L1, DST_ROWS_L1 = 2_048, 2_048
+FANOUT_L0, FANOUT_L1 = 25, 10   # edge slots a padded dst row
 N_FEAT = 602
 CACHE_ROWS = 81_537   # cache_frac 0.35 of reddit's 232,965 nodes
 
@@ -125,11 +126,12 @@ def test_sage_step_compiles(sds, compiled_kernels):
     def shapes(tree):
         return jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
 
-    def layer(tiles, dst_rows, last=False):
+    def layer(tiles, dst_rows, fanout, last=False):
         out = {
             "rows": sds((tiles,), jnp.int32),
             "cols": sds((tiles,), jnp.int32),
-            "blocks": sds((tiles, 128, 128)),
+            "slot": sds((dst_rows * fanout,), jnp.int32),
+            "off": sds((dst_rows * fanout,), jnp.int32),
             "counts": sds((dst_rows, 1)),
             "dst_pos": sds((dst_rows,), jnp.int32),
         }
@@ -138,8 +140,8 @@ def test_sage_step_compiles(sds, compiled_kernels):
             out["lmask"] = sds((dst_rows,))
         return out
 
-    layers = (layer(TILES_L0, DST_ROWS_L0),
-              layer(TILES_L1, DST_ROWS_L1, last=True))
+    layers = (layer(TILES_L0, DST_ROWS_L0, FANOUT_L0),
+              layer(TILES_L1, DST_ROWS_L1, FANOUT_L1, last=True))
     exe = eng._jit.lower(
         shapes(eng.params), shapes(eng.opt_state), shapes(eng.error),
         sds((SRC_ROWS_L0, N_FEAT)), layers,
