@@ -6,16 +6,30 @@ layer of it: one ``TrainerWorker`` built as ``gnn_trainer.run`` builds it
 store with its device tier), stepped through ``begin_epoch``, ``step`` and
 ``end_epoch`` by the harness so that it can time the window.
 
-Everything that belongs to one configuration, traffic mix or metric is a
-file found by its name in ``BENCHMARK.json``:
+Everything that belongs to one configuration, model, traffic mix or
+metric is a file found by its name in ``BENCHMARK.json`` or in the
+configuration:
 
 - ``configs/<config>.json``: sizes, lane settings, pinned cost-model
   parameters and the limits of the check;
+- ``models/<arch>.py``, named by the configuration's ``model.arch``: the
+  model's part of the yardstick. ``validate(config)`` refuses settings
+  its reference does not implement; ``program_options(config)`` gives the
+  ``RunConfig`` arguments that select the model in the program;
+  ``init_params(seed, config)`` the weights handed to the program, laid
+  out as the program's tree; ``forward(params, x, blocks, control=False)``
+  the plain reference forward (float32 at ``HIGHEST``, ``control``: three
+  bf16 passes); ``model_flops(layers, config)`` and
+  ``kernel_calls(layers, config) -> {kernel: [{"flops", "bytes"}, ...]}``
+  the algorithm's work from a batch's true counts;
 - ``traffic/<traffic>.json``: scenario, horizon, first measured epoch,
   steps per epoch, locality of the seeds;
 - ``metrics/<metric>.py``: a reader ``read(run) -> float | None`` over the
   run record this module builds (``None``: nothing to read there, and the
   metric is left out of the line).
+
+So a new model is additions only: ``models/<arch>.py``, its configuration
+file, its readers and its entries in ``BENCHMARK.json``.
 
 A run: the fixtures (cached), one epoch presampled from ``--seed``, the
 worker with the benchmark's weights, then a rehearsal in the epoch before
@@ -78,7 +92,8 @@ def _read_json(*parts) -> dict:
 
 
 def plan(spec: dict, workload: str, trace: bool) -> dict:
-    """The cell, its configuration and traffic files, and its metrics."""
+    """The cell, its configuration and traffic files, and its metrics;
+    exits on an unknown cell or model before any device work."""
     cells = {c["name"]: c for c in spec["workloads"]}
     if workload not in cells:
         raise SystemExit(f"unknown workload {workload!r}; "
@@ -88,6 +103,7 @@ def plan(spec: dict, workload: str, trace: bool) -> dict:
     with open(os.path.join(ROOT, entry["file"])) as f:
         config = json.load(f)
     traffic = _read_json("traffic", f"{cell['traffic']}.json")
+    model(config)
     return {"cell": cell, "config": config, "traffic": traffic,
             "metrics": metrics_for(spec, workload, trace)}
 
@@ -99,13 +115,39 @@ def metrics_for(spec: dict, workload: str, trace: bool) -> list[dict]:
     return spec["per_layer"] if trace else spec["end_to_end"]
 
 
-def reader(name: str):
-    """``metrics/<name>.py``'s ``read``."""
-    path = os.path.join(BENCH_DIR, "metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(f"metric_{name}", path)
+def _load(kind: str, name: str):
+    """The module ``<kind>/<name>.py``."""
+    path = os.path.join(BENCH_DIR, kind, f"{name}.py")
+    spec = importlib.util.spec_from_file_location(f"{kind}_{name}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def reader(name: str):
+    """``metrics/<name>.py``'s ``read``."""
+    return _load("metrics", name).read
+
+
+def known_models() -> list[str]:
+    """The ``arch`` names that have a module under ``models/``."""
+    names = os.listdir(os.path.join(BENCH_DIR, "models"))
+    return sorted(f[:-3] for f in names if f.endswith(".py"))
+
+
+def model(config: dict):
+    """``models/<arch>.py`` of the configuration's ``model.arch``, which
+    has validated the configuration's model settings."""
+    arch = config["model"]["arch"]
+    if arch not in known_models():
+        raise SystemExit(f"unknown model {arch!r} in configuration "
+                         f"{config['name']!r}; known: {known_models()}")
+    mod = _load("models", arch)
+    try:
+        mod.validate(config)
+    except ValueError as e:
+        raise SystemExit(f"configuration {config['name']!r}: {e}") from None
+    return mod
 
 
 # ----------------------------------------------------------------- device
@@ -147,6 +189,7 @@ def program_config(config: dict, traffic: dict, seed: int, q_fn, params):
         mem_budget=MemoryBudget(device_payloads=lane["device_payloads"]),
         async_pipeline=lane["async_pipeline"], compute="measured",
         seed=seed, params=params, q_fn=q_fn,
+        **model(config).program_options(config),
     )
 
 
@@ -248,8 +291,9 @@ def _to_host(tree):
     return jax.tree.map(lambda a: np.array(a, np.float64), tree)
 
 
-def inject_weights(engine, params0) -> None:
-    """Hand the benchmark's weights to the program's step state."""
+def inject_weights(engine, params0, arch: str) -> None:
+    """Hand the benchmark's weights (of model ``arch``) to the program's
+    step state."""
     import jax
 
     own = engine.params
@@ -257,8 +301,8 @@ def inject_weights(engine, params0) -> None:
         a.shape == b.shape for a, b in
         zip(jax.tree.leaves(own), jax.tree.leaves(params0)))
     if not same:
-        raise RuntimeError("the program's SAGE parameters are not laid out "
-                           "as the configuration states")
+        raise RuntimeError(f"the program's parameters are not laid out as "
+                           f"{arch}'s, as the configuration states")
     engine.params = params0
 
 
@@ -267,6 +311,7 @@ def run(plan_: dict, seed: int, seconds: float, trace: bool,
         t_start: float, require_chip: bool = True) -> dict:
     """One run; returns the result line as a dict."""
     config, traffic, cell = plan_["config"], plan_["traffic"], plan_["cell"]
+    mod = model(config)
     dev_info = device_info(cell.get("chips", 1), require_chip)
 
     sys.path.insert(0, os.path.join(ROOT, "src"))
@@ -287,16 +332,14 @@ def run(plan_: dict, seed: int, seconds: float, trace: bool,
     q_fn = fixtures.policy(config, params, log=log)
     cfg = program_config(config, traffic, seed, q_fn, params)
     worker, epoch_mbs = build_worker(cfg, graph, arrays["owner"])
-    dims = (config["graph"]["n_feat"], config["model"]["d_hidden"],
-            config["graph"]["n_classes"])
-    params0 = reference.init_params(seed, dims)
+    params0 = mod.init_params(seed, config)
     n_check = CHECK_STEPS
     spe, start = traffic["steps_per_epoch"], traffic["start_epoch"]
     stepper = Stepper(worker, epoch_mbs)
     obs = {"x": [], "x_want": [], "losses": []}
     try:
         engine = worker.engine
-        inject_weights(engine, params0)
+        inject_weights(engine, params0, config["model"]["arch"])
         # the engine's own first-step parity check (its forward against an
         # unjitted reference, compiled op by op at each new batch's exact
         # sizes) is skipped: the check below covers that forward and more,
@@ -370,22 +413,26 @@ def run(plan_: dict, seed: int, seconds: float, trace: bool,
     del worker, engine, stepper, real_step, observed
     gc.collect()
 
+    for r in steps:
+        r["model_flops"] = mod.model_flops(r["layers"], config)
+        r["kernel_calls"] = mod.kernel_calls(r["layers"], config)
     run_rec = {
         "setup_s": setup_s, "window_s": window_s, "steps": steps,
-        "dims": list(dims), "peaks": peak,
+        "n_feat": config["graph"]["n_feat"], "peaks": peak,
         "memory_peak_bytes": mem_peak, "trace": None,
     }
     if trace:
         path = xtrace.find_xplane(TRACE_DIR)
         run_rec["trace"] = (xtrace.reduce(xtrace.read_events(path))
                             if path else None)
-    checks = check(config, epoch_mbs, arrays, params0, obs)
+    checks = check(config, mod.forward, epoch_mbs, arrays, params0, obs)
     return result(plan_, run_rec, dev_info, checks)
 
 
-def check(config, epoch_mbs, arrays, params0, obs) -> dict:
+def check(config, forward, epoch_mbs, arrays, params0, obs) -> dict:
     """The epoch's sampled blocks against the fixture graph, and the plain
-    reference through the checked steps; each number beside its limit."""
+    reference (the model's ``forward``) through the checked steps; each
+    number beside its limit."""
     t0 = time.perf_counter()
     in_edges = reference.edge_keys(arrays["indptr"], arrays["indices"])
     fanouts = config["training"]["fanouts"]
@@ -396,7 +443,7 @@ def check(config, epoch_mbs, arrays, params0, obs) -> dict:
                                       arrays["labels"])
                for mb in epoch_mbs[:CHECK_STEPS]]
     opt = config["training"]["optimizer"]
-    ref = reference.train(params0, batches, opt)
+    ref = reference.train(forward, params0, batches, opt)
     numbers = {"blocks": float(block_faults),
                **reference.compare(obs, ref, params0, opt["b1"])}
     log(f"check: {len(epoch_mbs)} batches' blocks and {len(batches)} "

@@ -12,5 +12,5 @@ def read(run: dict) -> float | None:
     rows = sum(r["rows_gathered"] for r in run["steps"])
     if not n or not rows or run["peaks"] is None:
         return None
-    nbytes = work.gather_bytes(rows, run["dims"][0])
+    nbytes = work.gather_bytes(rows, run["n_feat"])
     return 100.0 * work.least_time(0.0, nbytes, run["peaks"]) / secs

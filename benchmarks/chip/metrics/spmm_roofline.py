@@ -1,6 +1,8 @@
 """Roofline share of the ``block_spmm`` kernel (forward and transposed):
-the least time the algorithm's work needs (``work.spmm_calls``, from true
-counts) over the kernel's device time summed from the trace."""
+the least time the algorithm's work needs (each step's ``kernel_calls``
+of this kernel from the model module, from true counts) over the
+kernel's device time summed from the trace. A model that makes no such
+call has no reading."""
 import work
 import xtrace
 
@@ -9,9 +11,10 @@ KERNEL = "block_spmm_kernel"
 
 def read(run: dict) -> float | None:
     secs, n = xtrace.kernel_seconds(run["trace"], KERNEL)
-    if not n or run["peaks"] is None:
+    calls = [c for r in run["steps"]
+             for c in r["kernel_calls"].get(KERNEL, [])]
+    if not n or not calls or run["peaks"] is None:
         return None
     least = sum(work.least_time(c["flops"], c["bytes"], run["peaks"])
-                for r in run["steps"]
-                for c in work.spmm_calls(r["layers"], run["dims"]))
+                for c in calls)
     return 100.0 * least / secs

@@ -3,11 +3,12 @@
     python3 benchmarks/chip/readings.py --workload <cell> --seeds 1,2,3
 
 For each seed it presamples the cell's epoch as a run does, makes the
-run's weights, and puts in the program's place, at the cell's own sizes:
+run's weights with the configuration's model module, and puts in the
+program's place, at the cell's own sizes:
 
-- ``control``: the reference with every matmul and the aggregation's
-  inputs at three bf16 passes (``high``), the precision below the
-  configuration's ``highest``;
+- ``control``: the reference (the model's ``forward``) with every matmul
+  and the aggregation's inputs at three bf16 passes (``high``), the
+  precision below the configuration's ``highest``;
 - ``unchanged``: a step that returns its state unchanged;
 - ``half_batch``: half of the batch's seeds left out, the mean taken over
   the rest;
@@ -34,15 +35,17 @@ import reference
 FAULTS = ("control", "unchanged", "half_batch", "altered_row")
 
 
-def planted(kind: str, params0, batches: list[dict], opt: dict) -> dict:
-    """The observations a program with fault ``kind`` would give."""
+def planted(kind: str, forward, params0, batches: list[dict], opt: dict
+            ) -> dict:
+    """The observations a program with fault ``kind`` would give, the
+    reference following the model's ``forward``."""
     import jax
 
     b1 = opt["b1"]
     x_want = [b["x"][: b["n_input"]] for b in batches]
     if kind == "unchanged":
         zeros = jax.tree.map(lambda p: np.zeros(p.shape), params0)
-        ref = reference.train(params0, batches, opt)
+        ref = reference.train(forward, params0, batches, opt)
         return {"x": x_want, "x_want": x_want, "losses": ref["losses"],
                 "mu": zeros, "params": harness._to_host(params0)}
     if kind == "half_batch":
@@ -54,12 +57,12 @@ def planted(kind: str, params0, batches: list[dict], opt: dict) -> dict:
         for b in batches:
             b["x"][0] += 1.0
     x_got = [b["x"][: b["n_input"]] for b in batches]
-    out = reference.train(params0, batches, opt, control=kind == "control")
+    out = reference.train(forward, params0, batches, opt,
+                          control=kind == "control")
     return {"x": x_got if kind == "altered_row" else x_want,
             "x_want": x_want,
             "losses": out["losses"],
-            "mu": {k: {n: g * (1 - b1) for n, g in v.items()}
-                   for k, v in out["grad"].items()},
+            "mu": jax.tree.map(lambda g: g * (1 - b1), out["grad"]),
             "params": out["params"]}
 
 
@@ -70,6 +73,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     plan = harness.plan(harness.load_spec(), args.workload, False)
     config, traffic = plan["config"], plan["traffic"]
+    mod = harness.model(config)
     harness.device_info(plan["cell"].get("chips", 1), require_chip=True)
 
     sys.path.insert(0, os.path.join(harness.ROOT, "src"))
@@ -82,8 +86,6 @@ def main(argv=None) -> int:
     graph = fixtures.program_graph(arrays)
     params = CostModelParams(**config["cost_model"])
     opt = config["training"]["optimizer"]
-    dims = (config["graph"]["n_feat"], config["model"]["d_hidden"],
-            config["graph"]["n_classes"])
     lowest: dict = {}
     for seed in (int(s) for s in args.seeds.split(",")):
         cfg = harness.program_config(config, traffic, seed, None, params)
@@ -91,11 +93,11 @@ def main(argv=None) -> int:
         batches = [reference.batch_arrays(mb, arrays["features"],
                                           arrays["labels"])
                    for mb in mbs[: harness.CHECK_STEPS]]
-        params0 = reference.init_params(seed, dims)
-        ref = reference.train(params0, batches, opt)
+        params0 = mod.init_params(seed, config)
+        ref = reference.train(mod.forward, params0, batches, opt)
         line = {"seed": seed}
         for kind in FAULTS:
-            obs = planted(kind, params0, batches, opt)
+            obs = planted(kind, mod.forward, params0, batches, opt)
             nums = reference.compare(obs, ref, params0, opt["b1"])
             nums.pop("update_leaf")
             line[kind] = nums

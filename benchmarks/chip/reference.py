@@ -1,19 +1,23 @@
 """Plain reference of the timed training step, and the comparison with it.
 
-GraphSAGE (Hamilton et al. 2017) with the mean aggregator, as the DGL
-distributed example trains it: per layer ``h_dst @ W_self + mean_{u->v}
-h_u @ W_neigh + b``, ReLU between layers, softmax cross entropy over the
-batch's seeds, AdamW. Written in straightforward ``jax.numpy`` float32 at
-``HIGHEST`` matmul precision, with per-edge gathers and a segment sum: no
-kernel, tile, cache or bucket of the program. It imports nothing of the
-program and takes nothing it made: the weights come from ``init_params``
-(the benchmark hands the same ones to the program), features and labels
-from the benchmark's own fixture.
+What any model shares: the sampled blocks checked against the fixture
+graph, a batch as the reference reads it (local edge lists per layer,
+input rows from the plain feature table), softmax cross entropy over the
+batch's seeds, AdamW, and the comparison with the program's observations.
+The model itself, its weights and its forward, is ``models/<arch>.py``'s,
+which the configuration names; ``loss_fn`` and ``train`` take its
+``forward``. Written in straightforward ``jax.numpy`` float32 at
+``HIGHEST`` matmul precision: no kernel, tile, cache or bucket of the
+program. It imports nothing of the program and takes nothing it made: the
+weights come from the model module's ``init_params`` (the benchmark hands
+the same ones to the program), features and labels from the benchmark's
+own fixture.
 
-The control is this reference with every matmul and the aggregation's
+The control is the reference with every matmul and the aggregation's
 inputs at three bf16 passes (``high``), the step below the ``highest``
-that the configuration states; it is written out here so that it means
-the same on every backend.
+that the configuration states: ``dot_3pass`` and ``round_3pass``, which a
+model's forward uses when called with ``control``; they are written out
+here so that they mean the same on every backend.
 """
 from __future__ import annotations
 
@@ -25,38 +29,15 @@ import numpy as np
 NEGLIGIBLE_GRAD = 1e-3
 
 
-def _key(seed: int):
+def key(seed: int):
+    """The PRNG key of ``seed``, any whole number up to 64 bits."""
     import jax
 
     return jax.random.fold_in(jax.random.PRNGKey(seed % (1 << 32)),
                               seed >> 32)
 
 
-def init_params(seed: int, dims: tuple) -> dict:
-    """Glorot-uniform weights (ReLU gain, as DGL's ``SAGEConv``) and zero
-    biases, made on the device in one jitted call from ``seed``."""
-    import jax
-    import jax.numpy as jnp
-
-    def make(key):
-        params = {}
-        for i in range(len(dims) - 1):
-            fi, fo = dims[i], dims[i + 1]
-            bound = np.sqrt(2.0) * np.sqrt(6.0 / (fi + fo))
-            k1, k2, key = jax.random.split(key, 3)
-            params[f"layer_{i}"] = {
-                "w_self": jax.random.uniform(k1, (fi, fo), jnp.float32,
-                                             -bound, bound),
-                "w_neigh": jax.random.uniform(k2, (fi, fo), jnp.float32,
-                                              -bound, bound),
-                "b": jnp.zeros((fo,), jnp.float32),
-            }
-        return params
-
-    return jax.jit(make)(_key(seed))
-
-
-def _dot_highest(a, b):
+def dot_highest(a, b):
     import jax
     import jax.numpy as jnp
 
@@ -71,7 +52,7 @@ def _split(a):
     return hi, lo
 
 
-def _dot_3pass(a, b):
+def dot_3pass(a, b):
     import jax.numpy as jnp
 
     (ah, al), (bh, bl) = _split(a), _split(b)
@@ -82,7 +63,7 @@ def _dot_3pass(a, b):
     return d(ah, bh) + d(ah, bl) + d(al, bh)
 
 
-def _round_3pass(x):
+def round_3pass(x):
     import jax.numpy as jnp
 
     hi, lo = _split(x)
@@ -201,28 +182,9 @@ def on_device(batch: dict) -> dict:
             "mask": jnp.asarray(batch["mask"])}
 
 
-def forward(params, x, blocks, control: bool = False):
-    import jax
-    import jax.numpy as jnp
-
-    dot = _dot_3pass if control else _dot_highest
-    h = x
-    for i, b in enumerate(blocks):
-        lp = params[f"layer_{i}"]
-        src_h = _round_3pass(h) if control else h
-        rows = b["dst_pos"].shape[0]
-        msg = src_h[b["src"]]
-        summed = jax.ops.segment_sum(msg, b["dst"], num_segments=rows)
-        count = jax.ops.segment_sum(jnp.ones_like(b["dst"], jnp.float32),
-                                    b["dst"], num_segments=rows)
-        agg = summed / jnp.maximum(count, 1.0)[:, None]
-        h_new = dot(h[b["dst_pos"]], lp["w_self"]) + dot(agg, lp["w_neigh"])
-        h_new = h_new + lp["b"]
-        h = jax.nn.relu(h_new) if i < len(blocks) - 1 else h_new
-    return h
-
-
-def loss_fn(params, batch, control: bool = False):
+def loss_fn(forward, params, batch, control: bool = False):
+    """Mean softmax cross entropy of ``forward``'s logits over the batch's
+    seeds."""
     import jax
     import jax.numpy as jnp
 
@@ -253,9 +215,10 @@ def adamw_step(params, state, grads, opt: dict):
     return params, (t, m, v)
 
 
-def train(params0, batches: list[dict], opt: dict, control: bool = False
-          ) -> dict:
-    """Follow the program through ``len(batches)`` steps from ``params0``.
+def train(forward, params0, batches: list[dict], opt: dict,
+          control: bool = False) -> dict:
+    """Follow the program through ``len(batches)`` steps from ``params0``,
+    with the model's ``forward``.
 
     Returns the loss of each step, the first step's gradient and the
     parameters after the last step, all on the host.
@@ -265,13 +228,14 @@ def train(params0, batches: list[dict], opt: dict, control: bool = False
     def to_host(tree):
         return jax.tree.map(lambda a: np.asarray(a, np.float64), tree)
 
-    grad = jax.jit(jax.value_and_grad(loss_fn), static_argnums=2)
+    grad = jax.jit(jax.value_and_grad(loss_fn, argnums=1),
+                   static_argnums=(0, 3))
     params = params0
     state = (0, jax.tree.map(lambda p: p * 0, params0),
              jax.tree.map(lambda p: p * 0, params0))
     losses, first_grad = [], None
     for batch in batches:
-        loss, g = grad(params, on_device(batch), control)
+        loss, g = grad(forward, params, on_device(batch), control)
         if first_grad is None:
             first_grad = to_host(g)
         params, state = adamw_step(params, state, g, opt)

@@ -1,4 +1,4 @@
-"""Chip benchmark: measured GraphSAGE training on the program's normal path.
+"""Chip benchmark: measured GNN training on the program's normal path.
 
     python3 benchmarks/chip/run.py --workload <cell> --seed <n> \\
         --seconds <run_seconds> --trace <0|1>

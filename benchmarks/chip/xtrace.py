@@ -12,6 +12,7 @@ innermost other host event (``bench.step/<event>``).
 from __future__ import annotations
 
 import glob
+import heapq
 import os
 
 OPS_LINE = "XLA Ops"
@@ -87,16 +88,40 @@ def gaps(busy: list[tuple], lo: float, hi: float) -> list[tuple]:
     return out
 
 
-def _label(t: float, host: list[tuple]) -> str:
-    """What the host was doing at ``t``: innermost bench span and event."""
-    covering = [h for h in host if h[0] <= t < h[1]]
-    spans = [h for h in covering if h[2].startswith(SPAN_PREFIX)
+def _innermost(events: list[tuple], times: list[float]) -> list:
+    """For each of ``times``, the name of the event ``(start, end, name)``
+    covering it (``start <= t < end``) that started last, the first listed
+    on a tie; ``None`` where none covers it. One sweep over the times in
+    order, the events that have started on a heap by their start."""
+    order = sorted(range(len(events)), key=lambda i: events[i][0])
+    out: list = [None] * len(times)
+    heap: list = []
+    k = 0
+    for q in sorted(range(len(times)), key=lambda j: times[j]):
+        t = times[q]
+        while k < len(order) and events[order[k]][0] <= t:
+            heapq.heappush(heap, (-events[order[k]][0], order[k]))
+            k += 1
+        # an event that has ended before t has ended for every later time
+        while heap and events[heap[0][1]][1] <= t:
+            heapq.heappop(heap)
+        if heap:
+            out[q] = events[heap[0][1]][2]
+    return out
+
+
+def _labels(gaps_: list[tuple], host: list[tuple]) -> list[str]:
+    """What the host was doing at each gap's midpoint: the innermost bench
+    span and, where there is one, the innermost other host event."""
+    mids = [(s + e) / 2 for s, e in gaps_]
+    spans = [h for h in host if h[2].startswith(SPAN_PREFIX)
              and h[2] != WINDOW_SPAN]
-    others = [h for h in covering if not h[2].startswith(SPAN_PREFIX)]
-    label = max(spans, key=lambda h: h[0])[2] if spans else "host"
-    if others:
-        label += "/" + max(others, key=lambda h: h[0])[2]
-    return label
+    others = [h for h in host if not h[2].startswith(SPAN_PREFIX)]
+    out = []
+    for span, other in zip(_innermost(spans, mids), _innermost(others, mids)):
+        label = "host" if span is None else span
+        out.append(label if other is None else f"{label}/{other}")
+    return out
 
 
 def reduce(events: dict) -> dict | None:
@@ -126,8 +151,7 @@ def reduce(events: dict) -> dict | None:
             rec[0] += min(e, hi) - max(s, lo)
             rec[1] += 1
     gap_sums: dict = {}
-    for s, e in all_gaps:
-        label = _label((s + e) / 2, host)
+    for (s, e), label in zip(all_gaps, _labels(all_gaps, host)):
         gap_sums[label] = gap_sums.get(label, 0.0) + (e - s)
     return {
         "window_s": hi - lo,

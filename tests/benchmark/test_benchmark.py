@@ -4,7 +4,7 @@ sizes.
     PYTHONPATH=src python -m pytest -q tests/benchmark
 
 The trace reduction on a synthetic trace and on a trace recorded on the
-chip, the work functions against hand counts, discovery of cells,
+chip, GraphSAGE's work functions against hand counts, discovery of cells,
 configurations and metrics by name, the command's refusal without a chip,
 and the correctness check: a sound run passes, and the control, the
 faults a training cell can have and a faulty sampler are caught.
@@ -91,6 +91,39 @@ def test_reduce_recorded_chip_trace():
     assert bd["idle_gaps"][0][1] > 25.0
 
 
+def _label_by_scan(t, host):
+    """The gap label at ``t`` by a plain scan over every host event."""
+    covering = [h for h in host if h[0] <= t < h[1]]
+    spans = [h for h in covering if h[2].startswith("bench.")
+             and h[2] != "bench.window"]
+    others = [h for h in covering if not h[2].startswith("bench.")]
+    label = max(spans, key=lambda h: h[0])[2] if spans else "host"
+    if others:
+        label += "/" + max(others, key=lambda h: h[0])[2]
+    return label
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_gap_labels_match_a_plain_scan(seed):
+    """The sweep that labels idle gaps gives what a scan over every host
+    event gives, ties in start and nested, equal and empty events
+    included."""
+    rng = np.random.default_rng(seed)
+    names = ["bench.step", "bench.window", "bench.x", "a", "b", "c"]
+    host = []
+    for _ in range(200):
+        s = float(rng.integers(0, 40)) / 4 if rng.random() < 0.5 \
+            else float(rng.random() * 10)
+        host.append((s, s + float(rng.choice([0.0, 0.25, rng.random()])),
+                     str(rng.choice(names))))
+    gaps_ = [(float(a), float(a + rng.random()))
+             for a in rng.random(300) * 10]
+    gaps_ += [(h[0], h[0]) for h in host[:20]] + [(h[1], h[1])
+                                                  for h in host[:20]]
+    assert xtrace._labels(gaps_, host) == [
+        _label_by_scan((s + e) / 2, host) for s, e in gaps_]
+
+
 def test_reduce_without_device_ops():
     assert xtrace.reduce({"devices": {}, "host": []}) is None
 
@@ -105,6 +138,14 @@ def test_idle_share_reader():
 LAYERS = [{"n_src": 10, "n_dst": 4, "n_edges": 12},
           {"n_src": 4, "n_dst": 2, "n_edges": 5}]
 DIMS = [6, 3, 2]
+# a GraphSAGE configuration of widths DIMS, as the model module reads it
+HAND = {"name": "hand",
+        "model": {"arch": "graphsage", "n_layers": 2, "d_hidden": DIMS[1],
+                  "aggregator": "mean", "dtype": "float32",
+                  "matmul_precision": "highest"},
+        "graph": {"n_feat": DIMS[0], "n_classes": DIMS[2]},
+        "training": {"fanouts": [10, 25]}}
+SAGE = harness.model(HAND)
 
 
 def test_model_flops_hand_count():
@@ -112,11 +153,11 @@ def test_model_flops_hand_count():
     # layer 1: agg 2*5*3=30, proj 2*2*2*3*2=48, three times, plus the
     # transposed aggregation 30
     want = (144 + 288 * 2) + (30 + 48 * 3 + 30)
-    assert work.model_flops(LAYERS, DIMS) == want
+    assert SAGE.model_flops(LAYERS, HAND) == want
 
 
 def test_spmm_calls_hand_count():
-    calls = work.spmm_calls(LAYERS, DIMS)
+    calls = SAGE.kernel_calls(LAYERS, HAND)["block_spmm_kernel"]
     assert [(c["layer"], c["pass"]) for c in calls] == [
         (0, "forward"), (1, "forward"), (1, "transposed")]
     assert calls[0]["flops"] == 2 * 12 * 6
@@ -135,15 +176,17 @@ def test_least_time_and_gather():
 
 
 def test_mfu_and_roofline_readers():
-    steps = [{"layers": LAYERS, "seeds": 2, "rows_gathered": 10}]
+    steps = [{"layers": LAYERS, "seeds": 2, "rows_gathered": 10,
+              "model_flops": SAGE.model_flops(LAYERS, HAND),
+              "kernel_calls": SAGE.kernel_calls(LAYERS, HAND)}]
     tr = xtrace.reduce(_synthetic())
-    run = {"steps": steps, "dims": DIMS, "peaks": PEAK, "window_s": 2.0,
-           "trace": tr}
+    run = {"steps": steps, "n_feat": DIMS[0], "peaks": PEAK,
+           "window_s": 2.0, "trace": tr}
     mfu = harness.reader("mfu")(run)
     assert mfu == pytest.approx(
-        100 * work.model_flops(LAYERS, DIMS) / 2.0 / PEAK["flops"])
+        100 * SAGE.model_flops(LAYERS, HAND) / 2.0 / PEAK["flops"])
     least = sum(work.least_time(c["flops"], c["bytes"], PEAK)
-                for c in work.spmm_calls(LAYERS, DIMS))
+                for c in SAGE.kernel_calls(LAYERS, HAND)["block_spmm_kernel"])
     spmm = harness.reader("spmm_roofline")
     secs, n = xtrace.kernel_seconds(tr, spmm.__globals__["KERNEL"])
     assert (secs, n) == (2.5, 2)
@@ -387,18 +430,17 @@ def test_control_fails_a_limit(tiny_cache):
     graph = fixtures.program_graph(arrays)
     params = CostModelParams(**config["cost_model"])
     opt = config["training"]["optimizer"]
-    dims = (config["graph"]["n_feat"], config["model"]["d_hidden"],
-            config["graph"]["n_classes"])
+    mod = harness.model(config)
     for seed in (1, 2, 3):
         cfg = harness.program_config(config, traffic, seed, None, params)
         mbs = harness.presample(cfg, graph, arrays["owner"])
         batches = [reference.batch_arrays(mb, arrays["features"],
                                           arrays["labels"])
                    for mb in mbs[: harness.CHECK_STEPS]]
-        p0 = reference.init_params(seed, dims)
-        ref = reference.train(p0, batches, opt)
+        p0 = mod.init_params(seed, config)
+        ref = reference.train(mod.forward, p0, batches, opt)
         nums = reference.compare(
-            readings.planted("control", p0, batches, opt), ref, p0,
-            opt["b1"])
+            readings.planted("control", mod.forward, p0, batches, opt), ref,
+            p0, opt["b1"])
         nums.pop("update_leaf")
         assert any(v > config["limits"][k] for k, v in nums.items()), nums
