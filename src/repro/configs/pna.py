@@ -1,5 +1,14 @@
 """PNA [arXiv:2004.05718]: 4 layers, d_hidden=75, aggregators
-mean-max-min-std, scalers identity-amplification-attenuation."""
+mean-max-min-std, scalers identity-amplification-attenuation.
+
+Departures from the paper's model and from PyG's ``examples/pna.py``
+(``models/gnn/pna.py`` implements them): one tower (no split of the
+hidden width into towers); LayerNorm after the update in place of the
+example's BatchNorm; no edge features in the message (the graphs this
+system trains on have none); a destination with no in-neighbour gets 0
+for all four aggregates. ``delta`` is a placeholder here: the measured
+lane sets it from its training graph (``pna.graph_delta``).
+"""
 from repro.configs.registry import ArchDef
 from repro.configs.shapes import GNN_SHAPES
 from repro.models.gnn.pna import PNAConfig

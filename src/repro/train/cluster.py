@@ -242,19 +242,12 @@ class ClusterReport:
         return rows
 
 
-def default_grad_bytes(graph, d_hidden: int = 16) -> float:
-    """fp32 bytes of the GraphSAGE model the trainer optionally runs
-    (matches ``gnn_trainer._init_model``: d_in -> 16 -> n_classes)."""
-    if graph.features is not None:
-        d_in = int(graph.features.shape[1])
-    else:
-        d_in = int(graph.feature_source.n_feat)
-    n_cls = int(graph.labels.max()) + 1
-    n_params = (
-        2 * d_in * d_hidden + d_hidden          # layer 1 (self+neigh) + bias
-        + 2 * d_hidden * n_cls + n_cls          # layer 2
-    )
-    return 4.0 * n_params
+def default_grad_bytes(graph, model: str = "sage") -> float:
+    """fp32 gradient bytes of ``model`` (``compute.MODELS``) sized for
+    ``graph``: ``compute.model_wire_bytes`` uncompressed."""
+    from repro.train.compute import model_wire_bytes
+
+    return model_wire_bytes(graph, "none", model=model)
 
 
 def build_cluster_traces(cfg, n_workers: int, silent_ranks: tuple = (),
@@ -519,14 +512,14 @@ def run_cluster(cfg, cluster: ClusterConfig | None = None,
     if cluster.grad_bytes is not None:
         grad_bytes = float(cluster.grad_bytes)
     elif cluster.grad_compression == "none":
-        grad_bytes = default_grad_bytes(graph)
+        grad_bytes = default_grad_bytes(graph, cfg.model)
     else:
         # compressed wire bytes replace the constant payload in the ring
         # collective — compression becomes an energy-visible knob
         from repro.train.compute import model_wire_bytes
 
         grad_bytes = model_wire_bytes(
-            graph, cluster.grad_compression, cluster.topk_frac
+            graph, cluster.grad_compression, cluster.topk_frac, cfg.model
         )
     staleness = (
         BoundedStalenessBarrier(

@@ -1,9 +1,11 @@
-"""Measured-compute lane: a real jitted GraphSAGE step on the hot path.
+"""Measured-compute lane: a real jitted GNN step on the hot path.
 
 Modeled mode charges ``CostModelParams.t_base`` for every trainer step;
 this module replaces that constant with the wall time of an actual
 forward/backward/optimizer step over the feature payloads the step
-resolved, with neighborhood aggregation dispatched through the
+resolved. ``RunConfig.model`` selects the model (``MODELS``): its config,
+init, forward, parity reference and wire bytes. GraphSAGE (``"sage"``,
+the default) dispatches neighborhood aggregation through the
 ``kernels.segment_mm`` block-sparse format:
 
   * on an accelerator backend the Pallas kernel (``block_spmm``) runs
@@ -28,8 +30,17 @@ fan-out, so the jitted step compiles once per size bucket; compilation
 happens ahead-of-time (``.lower().compile()``) and is excluded from the
 measured step time.
 
-The block path is parity-asserted against the ``models/gnn/common``
-scatter reference (``check_parity``, run automatically on the first
+PNA (``"pna"``) needs max, min and std of messages that depend on both
+endpoints, which no 0/1 tile carries. The sampler draws exactly the
+fan-out per destination, so the host uploads each layer's neighbour table
+(``kernels.fanout_agg.neighbour_table``: ``nbr [n_dst_pad, fan]`` and the
+real slots ``deg``) and the step aggregates with ``fanout_aggregate``
+(the ``fanout_aggregate_kernel`` on an accelerator, its XLA twin on CPU);
+``msg_slots`` counts the table's slots, of which ``pad_msg_slots`` belong
+to padded or neighbourless destinations.
+
+The step is parity-asserted against the model's scatter reference
+(``apply_blocks``; ``check_parity``, run automatically on the first
 step). The step's phases are host-clock spans of the worker's
 ``repro.obs.wall`` recorder (``engine.build``, ``engine.pad``,
 ``engine.upload``, ``engine.parity``, ``engine.compile``, ``engine.run``,
@@ -42,6 +53,7 @@ Gradient sync flows through ``grad_compression`` with error feedback;
 """
 from __future__ import annotations
 
+import dataclasses
 import time
 from typing import Callable
 
@@ -62,39 +74,70 @@ def _bucket(n: int) -> int:
     return 1 << max(int(n) - 1, 0).bit_length()
 
 
+MODELS = ("sage", "pna")
+
+
+def _d_in(graph) -> int:
+    if graph.features is not None:
+        return int(graph.features.shape[1])
+    return int(graph.feature_source.n_feat)
+
+
 def sage_config(graph, d_hidden: int = 16):
     """The paper's training model (Section VI-A) sized for ``graph`` —
     the single config shared by the modeled-mode model runner
     (``gnn_trainer._init_model``) and the measured lane."""
     from repro.models.gnn import sage
 
-    d_in = (
-        graph.features.shape[1]
-        if graph.features is not None
-        else graph.feature_source.n_feat
-    )
     return sage.SageConfig(
-        d_in=d_in, d_hidden=d_hidden,
+        d_in=_d_in(graph), d_hidden=d_hidden,
         n_classes=int(graph.labels.max()) + 1, n_layers=2, dropout=0.0,
     )
 
 
-def model_wire_bytes(graph, scheme: str = "none", frac: float = 0.05) -> float:
-    """Per-sync gradient payload bytes for the SAGE model on ``graph``
-    under a compression scheme (abstract param shapes; nothing is
-    materialized). ``scheme="none"`` equals the float32 gradient payload
-    of ``cluster.default_grad_bytes`` bit-for-bit."""
+def pna_config(graph):
+    """PNA at its published widths (``repro.configs.pna``) sized for
+    ``graph``, with ``delta`` the mean log in-degree of ``graph``."""
+    from repro.configs import pna as pna_arch
+    from repro.models.gnn import pna
+
+    cfg = pna_arch.make_config(d_in=_d_in(graph),
+                               n_classes=int(graph.labels.max()) + 1)
+    return dataclasses.replace(cfg, delta=pna.graph_delta(graph.csr.indptr))
+
+
+def model_config(graph, model: str = "sage"):
+    """The config of ``model`` (one of ``MODELS``) sized for ``graph``."""
+    if model == "sage":
+        return sage_config(graph)
+    if model == "pna":
+        return pna_config(graph)
+    raise ValueError(f"model must be one of {MODELS}, got {model!r}")
+
+
+def model_module(model: str):
+    """``repro.models.gnn.<model>``: its ``init``, ``apply_blocks`` and
+    ``apply_full``."""
+    from repro.models.gnn import pna, sage
+
+    return {"sage": sage, "pna": pna}[model]
+
+
+def model_wire_bytes(graph, scheme: str = "none", frac: float = 0.05,
+                     model: str = "sage") -> float:
+    """Per-sync gradient payload bytes for ``model`` on ``graph`` under a
+    compression scheme (abstract param shapes; nothing is materialized).
+    ``scheme="none"`` is ``cluster.default_grad_bytes``."""
     import jax
 
-    from repro.models.gnn import sage
-
-    params, _ = sage.init(jax.random.PRNGKey(0), sage_config(graph),
-                          abstract=True)
+    params, _ = model_module(model).init(
+        jax.random.PRNGKey(0), model_config(graph, model), abstract=True)
     return float(gc.wire_bytes(params, scheme, frac))
 
 
 class ComputeEngine:
-    """Real jitted SAGE step + timing + compression for one worker.
+    """Real jitted GNN step (``cfg.model``) + timing + compression for one
+    worker.
 
     ``clock`` is injectable (monotonic, ``time.perf_counter`` by default)
     so the determinism harness can drive the measured lane with a virtual
@@ -121,18 +164,25 @@ class ComputeEngine:
         if agg_impl not in ("pallas", "xla"):
             raise ValueError(f"unknown agg_impl {agg_impl!r}")
 
-        from repro.models.gnn import sage
-
         self.device_kind = jax.devices()[0].device_kind
         self.graph = graph
-        self.mcfg = sage_config(graph)
+        self.model = cfg.model
+        self.mcfg = model_config(graph, cfg.model)
+        if self.mcfg.n_layers != len(cfg.fanouts):
+            raise ValueError(
+                f"model {cfg.model!r} has {self.mcfg.n_layers} layers but "
+                f"fanouts {tuple(cfg.fanouts)} sample {len(cfg.fanouts)}: "
+                f"give one fan-out per layer"
+            )
+        self.module = model_module(cfg.model)
         self.tile = int(tile)
         self.agg_impl = agg_impl
         self.scheme = scheme
         self.topk_frac = float(getattr(cfg, "topk_frac", 0.05))
         self.clock = clock or time.perf_counter
         self.spans = spans
-        self.params, _ = sage.init(jax.random.PRNGKey(cfg.seed), self.mcfg)
+        self.params, _ = self.module.init(jax.random.PRNGKey(cfg.seed),
+                                          self.mcfg)
         self.opt = optim.adamw(3e-3)  # greenlint: literal-ok — must match
         # the modeled lane's _init_model lr exactly; plumbing a config
         # field only one lane reads would let the twins drift
@@ -154,75 +204,49 @@ class ComputeEngine:
         self.compile_s = 0.0
         self.n_compiles = 0
         self.h2d_bytes = 0       # everything step uploads
-        self.tiles = 0           # 128x128 tiles built, all layers
+        self.tiles = 0           # 128x128 tiles built, all layers (sage)
         self.pad_tiles = 0       # of which power-of-two padding
+        self.msg_slots = 0       # neighbour-table slots, all layers (pna)
+        self.pad_msg_slots = 0   # of padded or neighbourless destinations
         self.parity_max_diff: float | None = None
         self._parity_tol = 2e-3
 
     # ------------------------------------------------------------ prepare
     def prepare(self, mb):
-        """Block-sparse plan + pow2 bucketing for one mini-batch, uploaded:
-        ``(layers, x_rows, n_edges)`` with ``layers`` on the device."""
+        """The model's layer plans + pow2 bucketing for one mini-batch,
+        uploaded: ``(layers, x_rows, n_edges)`` with ``layers`` on the
+        device."""
         import jax
 
         host, x_rows, n_edges, _ = self._prepare(mb)
         return jax.device_put(host), x_rows, n_edges
 
     def _prepare(self, mb):
-        """``prepare`` on the host: the layers as numpy arrays, and how many
-        of their tiles are power-of-two padding.
+        """``prepare`` on the host: the layers as numpy arrays, and the
+        step's counters (``tiles``, ``pad_tiles``, ``msg_slots``,
+        ``pad_msg_slots``).
 
-        Each layer holds its tiles' ``rows``/``cols`` (padded to a power of
-        two with zero tiles on the last row-block) and its real edges'
-        ``slot``/``off`` (``segment_mm.sorted_edge_slots``), padded to the
-        dst bucket times the layer's largest in-degree (its fan-out) with
-        slots the device build scatters as zeros; masked edges count toward
-        the tile set but are not scattered (their weight is 0)."""
-        from repro.kernels.segment_mm import (
-            block_sparse_plan, sorted_edge_slots,
-        )
-
+        Each layer's destinations are padded to a power-of-two count of
+        128-row blocks; it holds ``dst_pos`` (padded rows point at row 0),
+        the model's plan (``_tile_plan`` or ``_neighbour_plan``) and, on
+        the last layer, ``labels`` and the label mask ``lmask``."""
         t = self.tile
         layers = []
         n_edges = 0
-        pad_tiles = 0
+        counts = dict.fromkeys(
+            ("tiles", "pad_tiles", "msg_slots", "pad_msg_slots"), 0)
         n_src_rows = _bucket(-(-len(mb.blocks[0].src_nodes) // t)) * t
         src_rows = n_src_rows
         for i, blk in enumerate(mb.blocks):
             n_dst_true = len(blk.dst_nodes)
-            n_dst_blocks = _bucket(-(-n_dst_true // t))
-            n_dst_pad = n_dst_blocks * t
-            rows, cols, slot, off, ndb, n_src_pad = block_sparse_plan(
-                blk.edge_src, blk.edge_dst, n_dst_pad, src_rows, t, t
-            )
-            assert n_src_pad == src_rows and ndb == n_dst_blocks
-            nbp = _bucket(len(rows))
-            if nbp > len(rows):
-                pad = nbp - len(rows)
-                pad_tiles += pad
-                # padding tiles stay zero and point at the last row-block
-                # (rows stay sorted; they accumulate nothing)
-                rows = np.concatenate(
-                    [rows, np.full(pad, ndb - 1, np.int32)]
-                )
-                cols = np.concatenate([cols, np.zeros(pad, np.int32)])
-            indeg = np.bincount(
-                blk.edge_dst[blk.edge_mask], minlength=n_dst_pad
-            )
-            slot, off = sorted_edge_slots(
-                slot, off, blk.edge_mask, n_dst_pad * int(indeg.max()),
-                nbp, t * t,
-            )
+            n_dst_pad = _bucket(-(-n_dst_true // t)) * t
+            if self.model == "pna":
+                layer = self._neighbour_plan(blk, n_dst_pad, counts)
+            else:
+                layer = self._tile_plan(blk, n_dst_pad, src_rows, counts)
             dst_pos = np.zeros(n_dst_pad, np.int32)
             dst_pos[:n_dst_true] = blk.dst_pos
-            layer = {
-                "rows": rows,
-                "cols": cols,
-                "slot": slot,
-                "off": off,
-                "counts": np.maximum(indeg, 1).astype(np.float32)[:, None],
-                "dst_pos": dst_pos,
-            }
+            layer["dst_pos"] = dst_pos
             if i == len(mb.blocks) - 1:
                 labels = np.zeros(n_dst_pad, self.labels_np.dtype)
                 labels[:n_dst_true] = self.labels_np[blk.dst_nodes]
@@ -233,7 +257,67 @@ class ComputeEngine:
             layers.append(layer)
             n_edges += int(blk.edge_mask.sum())
             src_rows = n_dst_pad
-        return tuple(layers), n_src_rows, n_edges, pad_tiles
+        return tuple(layers), n_src_rows, n_edges, counts
+
+    def _tile_plan(self, blk, n_dst_pad: int, src_rows: int,
+                   counts: dict) -> dict:
+        """A SAGE layer's block-sparse plan: its tiles' ``rows``/``cols``
+        (padded to a power of two with zero tiles on the last row-block)
+        and its real edges' ``slot``/``off``
+        (``segment_mm.sorted_edge_slots``), padded to the dst bucket times
+        the layer's largest in-degree (its fan-out) with slots the device
+        build scatters as zeros; masked edges count toward the tile set but
+        are not scattered (their weight is 0). ``counts`` gains the tiles
+        and their padding."""
+        from repro.kernels.segment_mm import (
+            block_sparse_plan, sorted_edge_slots,
+        )
+
+        t = self.tile
+        n_dst_blocks = n_dst_pad // t
+        rows, cols, slot, off, ndb, n_src_pad = block_sparse_plan(
+            blk.edge_src, blk.edge_dst, n_dst_pad, src_rows, t, t
+        )
+        assert n_src_pad == src_rows and ndb == n_dst_blocks
+        nbp = _bucket(len(rows))
+        if nbp > len(rows):
+            pad = nbp - len(rows)
+            counts["pad_tiles"] += pad
+            # padding tiles stay zero and point at the last row-block
+            # (rows stay sorted; they accumulate nothing)
+            rows = np.concatenate(
+                [rows, np.full(pad, ndb - 1, np.int32)]
+            )
+            cols = np.concatenate([cols, np.zeros(pad, np.int32)])
+        counts["tiles"] += nbp
+        indeg = np.bincount(
+            blk.edge_dst[blk.edge_mask], minlength=n_dst_pad
+        )
+        slot, off = sorted_edge_slots(
+            slot, off, blk.edge_mask, n_dst_pad * int(indeg.max()),
+            nbp, t * t,
+        )
+        return {
+            "rows": rows,
+            "cols": cols,
+            "slot": slot,
+            "off": off,
+            "counts": np.maximum(indeg, 1).astype(np.float32)[:, None],
+        }
+
+    def _neighbour_plan(self, blk, n_dst_pad: int, counts: dict) -> dict:
+        """A PNA layer's neighbour table (``fanout_agg.neighbour_table``)
+        over the padded destinations: ``nbr`` and the real slots ``deg``.
+        ``counts`` gains its slots, and those of destinations with no real
+        slot (padding, or no in-neighbour)."""
+        from repro.kernels.fanout_agg import neighbour_table
+
+        nbr, deg = neighbour_table(blk.edge_src, blk.edge_dst,
+                                   blk.edge_mask, n_dst_pad)
+        fan = nbr.shape[1]
+        counts["msg_slots"] += n_dst_pad * fan
+        counts["pad_msg_slots"] += int(np.count_nonzero(deg == 0)) * fan
+        return {"nbr": nbr, "deg": deg}
 
     def pad_input(self, x_in: np.ndarray, x_rows: int) -> np.ndarray:
         x = np.zeros((x_rows, self.mcfg.d_in), np.float32)
@@ -276,11 +360,14 @@ class ComputeEngine:
         return y / layer["counts"]
 
     def _forward(self, params, x_pad, layers):
-        """Block-path SAGE forward over prepared layers (padded rows), at
-        ``MATMUL_PRECISION``; each layer's tiles are built from its plan
-        before its aggregation."""
+        """The model's forward over prepared layers (padded rows), at
+        ``MATMUL_PRECISION``. SAGE's block path builds each layer's tiles
+        from its plan before its aggregation."""
         import jax
 
+        if self.model == "pna":
+            with jax.default_matmul_precision(MATMUL_PRECISION):
+                return self._pna_forward(params, x_pad, layers)
         h = x_pad
         with jax.default_matmul_precision(MATMUL_PRECISION):
             for i, layer in enumerate(layers):
@@ -293,6 +380,23 @@ class ComputeEngine:
                     h_new = jax.nn.relu(h_new)
                 h = h_new
         return h
+
+    def _pna_forward(self, params, x_pad, layers):
+        """PNA over the neighbour tables: each layer projects its sources
+        and destinations once per node, aggregates the per-edge messages
+        with ``fanout_aggregate`` and applies ``pna.update``."""
+        from repro.kernels.fanout_agg import fanout_aggregate
+
+        h = x_pad @ params["w_in"] + params["b_in"]
+        for i, layer in enumerate(layers):
+            lp = params[f"layer_{i}"]
+            h_dst = h[layer["dst_pos"]]
+            aggs = fanout_aggregate(
+                h @ lp["w_msg_src"], h_dst @ lp["w_msg_dst"] + lp["b_msg"],
+                layer["nbr"], layer["deg"], impl=self.agg_impl,
+            )
+            h = self.module.update(lp, self.mcfg, h_dst, aggs, layer["deg"])
+        return h @ params["w_out"] + params["b_out"]
 
     def _step_fn(self, params, opt_state, error, x_pad, layers):
         import jax
@@ -330,8 +434,10 @@ class ComputeEngine:
         parity check are in neither (compilation is accounted in
         ``compile_s``). Returns the sum of the two spans, the step's
         compute time as the meter charges it. ``h2d_bytes`` counts the
-        upload, ``tiles`` and ``pad_tiles`` the tiles the step builds (the
-        first step's parity check uploads its own copies, not counted).
+        upload, ``tiles`` and ``pad_tiles`` the tiles the step builds,
+        ``msg_slots`` and ``pad_msg_slots`` its neighbour tables' slots
+        (the first step's parity check uploads its own copies, not
+        counted).
         """
         import jax
 
@@ -339,16 +445,17 @@ class ComputeEngine:
         with spans.span("engine.step"):
             t = self.clock()
             with spans.span("engine.build"):
-                host, x_rows, n_edges, pad_tiles = self._prepare(mb)
+                host, x_rows, n_edges, counts = self._prepare(mb)
             with spans.span("engine.pad"):
                 x_pad = self.pad_input(np.asarray(x_in, np.float32), x_rows)
             nbytes = sum(a.nbytes for a in jax.tree.leaves((host, x_pad)))
-            tiles = sum(len(layer["rows"]) for layer in host)
             self.h2d_bytes += nbytes
-            self.tiles += tiles
-            self.pad_tiles += pad_tiles
-            with spans.span("engine.upload", h2d_bytes=nbytes, tiles=tiles,
-                            pad_tiles=pad_tiles, edges=n_edges):
+            self.tiles += counts["tiles"]
+            self.pad_tiles += counts["pad_tiles"]
+            self.msg_slots += counts["msg_slots"]
+            self.pad_msg_slots += counts["pad_msg_slots"]
+            with spans.span("engine.upload", h2d_bytes=nbytes, edges=n_edges,
+                            **counts):
                 layers, x_dev = jax.block_until_ready(
                     jax.device_put((host, x_pad)))
             prep = self.clock() - t
@@ -357,9 +464,7 @@ class ComputeEngine:
                     self.check_parity(mb, x_in, _prep=(layers, x_rows))
             args = (self.params, self.opt_state, self.error, x_dev, layers)
             sig = (x_pad.shape,) + tuple(
-                (l["rows"].shape[0], l["counts"].shape[0], l["slot"].shape[0])
-                for l in layers
-            )
+                a.shape for a in jax.tree.leaves(layers))
             if sig not in self._exec:
                 with spans.span("engine.compile"):
                     t0 = self.clock()
@@ -388,19 +493,16 @@ class ComputeEngine:
     # ------------------------------------------------------------- parity
     def check_parity(self, mb, x_in: np.ndarray, tol: float | None = None,
                      _prep=None):
-        """Assert block-path forward == scatter reference on this batch.
+        """Assert the step's forward == the scatter reference on this batch.
 
-        The reference is ``sage.apply_blocks`` (per-edge gather +
-        ``common.scatter_sum``/mean) on the UNPADDED blocks; the block
-        path must agree on every valid dst row within float-accumulation
-        tolerance (summation order differs between the two). The block
-        path runs at ``MATMUL_PRECISION``, as the trained step does; the
-        reference runs at full float32.
+        The reference is the model's ``apply_blocks`` (per-edge gather +
+        ``common`` scatter ops) on the UNPADDED blocks; the step's forward
+        must agree on every valid dst row within float-accumulation
+        tolerance (summation order differs between the two). The step
+        runs at ``MATMUL_PRECISION``; the reference runs at full float32.
         """
         import jax
         import jax.numpy as jnp
-
-        from repro.models.gnn import sage
 
         tol = self._parity_tol if tol is None else tol
         if _prep is None:
@@ -419,7 +521,7 @@ class ComputeEngine:
         ]
         got = self._fwd_jit(self.params, jnp.asarray(x_pad), layers)
         with jax.default_matmul_precision("highest"):
-            ref = sage.apply_blocks(
+            ref = self.module.apply_blocks(
                 self.params, self.mcfg,
                 jnp.asarray(np.asarray(x_in, np.float32)), ref_blocks,
             )
@@ -429,7 +531,7 @@ class ComputeEngine:
         self.parity_max_diff = float(diff.max()) if diff.size else 0.0
         if self.parity_max_diff > tol:
             raise AssertionError(
-                f"block-path/scatter parity violated: max |diff| "
+                f"step/scatter parity violated: max |diff| "
                 f"{self.parity_max_diff:.3e} > {tol:.0e} "
                 f"(agg_impl={self.agg_impl})"
             )
@@ -440,7 +542,7 @@ class ComputeEngine:
         from repro.train import gnn_trainer as gt
 
         return gt._model_eval({"params": self.params, "cfg": self.mcfg},
-                              graph)
+                              graph, apply_full=self.module.apply_full)
 
     def calibration_samples(self) -> tuple[np.ndarray, np.ndarray]:
         """(n_edges, step_s) pairs for ``calibration.calibrate_compute``."""

@@ -105,12 +105,15 @@ class RunConfig:
                                      # byte budget. None (or an unlimited
                                      # budget) keeps the legacy monolithic
                                      # in-RAM store bit-for-bit.
-    compute: str = "modeled"         # "measured" runs the real jitted SAGE
+    compute: str = "modeled"         # "measured" runs the real jitted GNN
                                      # step (train/compute.ComputeEngine)
                                      # each trainer step and charges its
                                      # measured wall time where t_base is
                                      # charged today. "modeled" keeps the
                                      # constant-t_base lane bit-for-bit.
+    model: str = "sage"              # the measured lane's model
+                                     # (compute.MODELS): "sage" (2x16 mean)
+                                     # or "pna" (4x75); one fan-out a layer
     grad_compression: str = "none"   # measured-lane gradient sync scheme:
                                      # "none" | "int8" | "topk" (error
                                      # feedback; wire bytes feed the ring
@@ -371,11 +374,9 @@ def _init_model(graph, cfg: RunConfig):
 
     from repro import optim
     from repro.models.gnn import sage
+    from repro.train.compute import sage_config
 
-    mcfg = sage.SageConfig(
-        d_in=graph.features.shape[1], d_hidden=16,
-        n_classes=int(graph.labels.max()) + 1, n_layers=2, dropout=0.0,
-    )
+    mcfg = sage_config(graph)
     params, _ = sage.init(jax.random.PRNGKey(cfg.seed), mcfg)
     opt = optim.adamw(3e-3)
 
@@ -420,17 +421,20 @@ def _model_step(state, mb):
     return state
 
 
-def _model_eval(state, graph, n_eval: int = 2048):
+def _model_eval(state, graph, n_eval: int = 2048, apply_full=None):
+    """Accuracy of ``state``'s model (``apply_full``: SAGE's unless
+    given) on the induced subgraph of the first ``n_eval`` nodes."""
     import jax.numpy as jnp
 
     from repro.models.gnn import sage
     from repro.models.gnn.common import accuracy
 
+    apply_full = apply_full or sage.apply_full
     x = jnp.asarray(graph.features[:n_eval])
     # evaluate on the induced subgraph of the first n_eval nodes
     ei = graph.edge_index
     m = (ei[0] < n_eval) & (ei[1] < n_eval)
-    logits = sage.apply_full(
+    logits = apply_full(
         state["params"], state["cfg"], x, jnp.asarray(ei[:, m])
     )
     return float(accuracy(logits, jnp.asarray(graph.labels[:n_eval])))

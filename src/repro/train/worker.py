@@ -257,8 +257,8 @@ class TrainerWorker:
             )
         self.engine = None
         if cfg.compute == "measured" and self.mbs is not None:
-            # measured lane: real jitted SAGE step each trainer step; its
-            # wall time replaces the modeled t_base charge below
+            # measured lane: real jitted GNN step (cfg.model) each trainer
+            # step; its wall time replaces the modeled t_base charge below
             from repro.train.compute import ComputeEngine
 
             self.engine = ComputeEngine(graph, cfg, spans=self.spans)

@@ -1,6 +1,7 @@
 """Compile the training path's TPU programs for a described (not attached)
 TPU v5e, at the shapes of a reddit-width measured step (batch 2000,
-fan-out (10, 25), 602-wide features).
+fan-out (10, 25), 602-wide features; PNA: fan-out 10 at each of 4 layers,
+75 wide).
 
 Nothing runs: the TPU compiler refuses here what it would refuse on the
 chip (unaligned block shapes, too much VMEM, a kernel without a VJP), at
@@ -20,6 +21,12 @@ TILES_L1, DST_ROWS_L1 = 2_048, 2_048
 FANOUT_L0, FANOUT_L1 = 25, 10   # edge slots a padded dst row
 N_FEAT = 602
 CACHE_ROWS = 81_537   # cache_frac 0.35 of reddit's 232,965 nodes
+# PNA's buckets in the pna-reddit cell (locality 0.75): a 4-hop frontier
+# of ~232k input rows, then ~160k, ~42k, ~11k and ~1,990 destinations,
+# 10 slots each
+PNA_SRC_ROWS = 262_144
+PNA_DST_ROWS = (262_144, 65_536, 16_384, 2_048)
+PNA_FANOUT, PNA_WIDTH = 10, 75
 
 
 @pytest.fixture(scope="module")
@@ -57,9 +64,11 @@ def sds(topo):
 def compiled_kernels(monkeypatch):
     """The described chip is not the backend, so ``default_interpret``
     would pick the CPU interpreter; force the compiled kernel."""
+    from repro.kernels.fanout_agg import ops
     from repro.kernels.segment_mm import kernel
 
     monkeypatch.setattr(kernel, "default_interpret", lambda: False)
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
 
 
 def _spmm_args(sds, tiles, src_rows, f):
@@ -150,4 +159,59 @@ def test_sage_step_compiles(sds, compiled_kernels):
     print(mem)
     # two forward aggregations and the layer-1 backward run as kernels
     assert exe.as_text().count("tpu_custom_call") == 3
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_fanout_aggregate_compiles(sds):
+    """The input layer's call: 10 gathered slots of 262,144 destination
+    rows, 75 columns padded to 128 lanes."""
+    from repro.kernels.fanout_agg.kernel import fanout_aggregate_kernel
+
+    n = PNA_DST_ROWS[0]
+    exe = fanout_aggregate_kernel.lower(
+        sds((PNA_FANOUT, n, 128)), sds((n, 128)), sds((n, 1)),
+        interpret=False,
+    ).compile()
+    assert "tpu_custom_call" in exe.as_text()
+
+
+def test_pna_step_compiles(sds, compiled_kernels):
+    import types
+
+    from repro.graph.structure import CSR
+    from repro.train import gnn_trainer as gt
+    from repro.train.compute import ComputeEngine
+
+    graph = types.SimpleNamespace(
+        features=np.zeros((1, N_FEAT), np.float32),
+        labels=np.arange(41, dtype=np.int32),
+        csr=CSR(indptr=np.array([0, 50]), indices=np.zeros(50, np.int64)),
+    )
+    eng = ComputeEngine(graph, gt.RunConfig(model="pna",
+                                            fanouts=(PNA_FANOUT,) * 4),
+                        agg_impl="pallas")
+    assert eng.mcfg.d_hidden == PNA_WIDTH
+
+    def shapes(tree):
+        return jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
+
+    def layer(dst_rows, last=False):
+        out = {"nbr": sds((dst_rows, PNA_FANOUT), jnp.int32),
+               "deg": sds((dst_rows,)),
+               "dst_pos": sds((dst_rows,), jnp.int32)}
+        if last:
+            out["labels"] = sds((dst_rows,), jnp.int32)
+            out["lmask"] = sds((dst_rows,))
+        return out
+
+    layers = tuple(layer(n, last=i == len(PNA_DST_ROWS) - 1)
+                   for i, n in enumerate(PNA_DST_ROWS))
+    exe = eng._jit.lower(
+        shapes(eng.params), shapes(eng.opt_state), shapes(eng.error),
+        sds((PNA_SRC_ROWS, N_FEAT)), layers,
+    ).compile()
+    mem = exe.memory_analysis()
+    print(mem)
+    # the four forward aggregations run as the kernel; the backward is XLA
+    assert exe.as_text().count("tpu_custom_call") == 4
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
