@@ -17,7 +17,7 @@ the default) dispatches neighborhood aggregation through the
 The edge-list -> block conversion is split between host and device.
 The host computes each layer's index plan (``segment_mm.block_sparse_plan``:
 the tile ids, and each edge's tile slot and in-tile offset) and uploads
-it with the padded input; the compiled step scatters the tiles from the
+it with the input; the compiled step scatters the tiles from the
 plan on the device (``segment_mm.tiles_from_plan``) before each layer's
 aggregation. A plan is a few bytes per edge where the tiles are 64 KiB
 each (at reddit width one batch's layer-0 tiles take ~4.3 GB, for ~10
@@ -46,7 +46,16 @@ step). The step's phases are host-clock spans of the worker's
 ``engine.upload``, ``engine.parity``, ``engine.compile``, ``engine.run``,
 ``engine.free`` inside ``engine.step``), and its uploads are counted exactly:
 ``h2d_bytes``, the 128x128 ``tiles`` built of which ``pad_tiles`` are
-power-of-two padding, and the real ``edges`` scattered into them.
+power-of-two padding, the real ``edges`` scattered into them, the real
+``input_rows`` uploaded and the ``pad_input_rows`` zero rows the device
+adds to them.
+
+The step's input, padded to its bucket, is assembled on the device: the
+real rows go up in chunks of ``INPUT_CHUNK_ROWS`` (the last one padded
+with zeros in one reused host buffer) and are written into a zeroed
+device buffer (``_assembly``, compiled once per input bucket), so no
+padded copy is built on the host.
+
 Gradient sync flows through ``grad_compression`` with error feedback;
 ``sync_wire_bytes`` is what the cluster driver feeds into
 ``ring_collective_cost`` in place of the uncompressed payload.
@@ -69,12 +78,27 @@ _SCHEMES = ("none", "int8", "topk")
 MATMUL_PRECISION = "highest"
 
 
+# Rows of the step's input uploaded at a time: 128 blocks of 128 rows
+# (39 MB at reddit width). A smaller bucket is one chunk of its own size;
+# buckets and this are powers of two of 128-row blocks, so a chunk always
+# divides its bucket.
+INPUT_CHUNK_ROWS = 128 * 128
+
+
 def _bucket(n: int) -> int:
     """Next power of two >= n (min 1): bounds distinct jit signatures."""
     return 1 << max(int(n) - 1, 0).bit_length()
 
 
 MODELS = ("sage", "pna")
+
+
+def _place(buf, rows, row):
+    """``buf`` with ``rows`` written from row ``row`` on: the step's input
+    assembly, one uploaded chunk at a time."""
+    from jax import lax
+
+    return lax.dynamic_update_slice(buf, rows, (row, 0))
 
 
 def _d_in(graph) -> int:
@@ -196,6 +220,8 @@ class ComputeEngine:
         self._jit = jax.jit(self._step_fn)
         self._fwd_jit = jax.jit(self._forward)
         self._exec: dict = {}            # shape signature -> AOT executable
+        self._assemblers: dict = {}      # (x_rows, chunk) -> (zeros, place)
+        self._tail: np.ndarray | None = None  # the last chunk's host rows
 
         self.losses: list[float] = []
         self.prep_s: list[float] = []
@@ -208,6 +234,8 @@ class ComputeEngine:
         self.pad_tiles = 0       # of which power-of-two padding
         self.msg_slots = 0       # neighbour-table slots, all layers (pna)
         self.pad_msg_slots = 0   # of padded or neighbourless destinations
+        self.input_rows = 0      # real input rows uploaded
+        self.pad_input_rows = 0  # zero rows the device adds up to the bucket
         self.parity_max_diff: float | None = None
         self._parity_tol = 2e-3
 
@@ -235,7 +263,7 @@ class ComputeEngine:
         n_edges = 0
         counts = dict.fromkeys(
             ("tiles", "pad_tiles", "msg_slots", "pad_msg_slots"), 0)
-        n_src_rows = _bucket(-(-len(mb.blocks[0].src_nodes) // t)) * t
+        n_src_rows = self._x_rows(mb)
         src_rows = n_src_rows
         for i, blk in enumerate(mb.blocks):
             n_dst_true = len(blk.dst_nodes)
@@ -258,6 +286,12 @@ class ComputeEngine:
             n_edges += int(blk.edge_mask.sum())
             src_rows = n_dst_pad
         return tuple(layers), n_src_rows, n_edges, counts
+
+    def _x_rows(self, mb) -> int:
+        """The input's bucket: its rows padded to a power-of-two count of
+        128-row blocks."""
+        t = self.tile
+        return _bucket(-(-len(mb.blocks[0].src_nodes) // t)) * t
 
     def _tile_plan(self, blk, n_dst_pad: int, src_rows: int,
                    counts: dict) -> dict:
@@ -320,9 +354,63 @@ class ComputeEngine:
         return {"nbr": nbr, "deg": deg}
 
     def pad_input(self, x_in: np.ndarray, x_rows: int) -> np.ndarray:
+        """The input padded to ``x_rows`` on the host: the layout
+        ``step`` assembles on the device, and the input ``check_parity``
+        feeds the forward."""
         x = np.zeros((x_rows, self.mcfg.d_in), np.float32)
         x[: len(x_in)] = x_in
         return x
+
+    def _assembly(self, x_rows: int):
+        """``(chunk, zeros, place)`` for an input bucket of ``x_rows``:
+        ``zeros()`` gives the ``(x_rows, d_in)`` device buffer, and
+        ``place(buf, rows, row)`` writes ``chunk`` uploaded rows at
+        ``row`` into ``buf``, which it takes over (donated). Both are
+        compiled ahead of time on a bucket's first use, into
+        ``compile_s``."""
+        import jax
+        import jax.numpy as jnp
+
+        chunk = min(INPUT_CHUNK_ROWS, x_rows)
+        if (x_rows, chunk) not in self._assemblers:
+            buf = jax.ShapeDtypeStruct((x_rows, self.mcfg.d_in), jnp.float32)
+            rows = jax.ShapeDtypeStruct((chunk, self.mcfg.d_in), jnp.float32)
+            row = jax.ShapeDtypeStruct((), jnp.int32)
+            zeros = jax.jit(lambda: jnp.zeros(buf.shape, buf.dtype))
+            place = jax.jit(_place, donate_argnums=0)
+            with self.spans.span("engine.compile"):
+                t0 = self.clock()
+                self._assemblers[x_rows, chunk] = (
+                    zeros.lower().compile(),
+                    place.lower(buf, rows, row).compile())
+                self.compile_s += self.clock() - t0
+        return (chunk,) + self._assemblers[x_rows, chunk]
+
+    def _input_chunks(self, x_in: np.ndarray, chunk: int) -> list:
+        """``x_in``'s rows as ``chunk``-row arrays: row views of ``x_in``,
+        then its remaining rows in a reused host buffer whose other rows
+        are zero."""
+        n_full = len(x_in) // chunk
+        out = [x_in[i * chunk:(i + 1) * chunk] for i in range(n_full)]
+        rest = x_in[n_full * chunk:]
+        if len(rest):
+            if self._tail is None or len(self._tail) != chunk:
+                self._tail = np.empty((chunk, x_in.shape[1]), np.float32)
+            self._tail[:len(rest)] = rest
+            self._tail[len(rest):] = 0.0
+            out.append(self._tail)
+        return out
+
+    def _device_input(self, chunks: list, chunk: int, zeros, place):
+        """The input padded to its bucket on the device, as ``pad_input``
+        lays it out: ``chunks`` (``_input_chunks``) uploaded and written
+        in order into the zeroed buffer. Not waited for."""
+        import jax
+
+        x_dev = zeros()
+        for i, rows in enumerate(jax.device_put(chunks)):
+            x_dev = place(x_dev, rows, np.int32(i * chunk))
+        return x_dev
 
     # ------------------------------------------------------------ forward
     def _tiles(self, layer):
@@ -427,43 +515,55 @@ class ComputeEngine:
 
         ``x_in`` are the resolved feature rows for ``mb.input_nodes``.
         Two spans are timed: ``prep_s``, the host build of the layers'
-        plans and the padded input, their upload (waited for) and the free
-        of the host copies; and ``step_s``, the compiled step alone (the
-        tile scatter included), which is what ``calibration_samples``
-        fits. AOT compilation on a new shape bucket and the first step's
-        parity check are in neither (compilation is accounted in
-        ``compile_s``). Returns the sum of the two spans, the step's
-        compute time as the meter charges it. ``h2d_bytes`` counts the
-        upload, ``tiles`` and ``pad_tiles`` the tiles the step builds,
-        ``msg_slots`` and ``pad_msg_slots`` its neighbour tables' slots
-        (the first step's parity check uploads its own copies, not
+        plans, their upload with the input's real rows and the input's
+        assembly on the device (waited for), and the free of the host
+        copies; and ``step_s``, the compiled step alone (the tile scatter
+        included), which is what ``calibration_samples`` fits. AOT
+        compilation on a new shape bucket (the assembly's and the step's)
+        and the first step's parity check are in neither (compilation is
+        accounted in ``compile_s``; ``n_compiles`` counts the step's
+        executables, and the assembly compiles only with a new input
+        bucket, so with a new step executable). Returns the sum of the
+        two spans, the step's compute time as the meter charges it.
+        ``h2d_bytes`` counts the upload, ``input_rows`` and
+        ``pad_input_rows`` the input's real rows and the zero rows the
+        device adds, ``tiles`` and ``pad_tiles`` the tiles the step
+        builds, ``msg_slots`` and ``pad_msg_slots`` its neighbour tables'
+        slots (the first step's parity check uploads its own copies, not
         counted).
         """
         import jax
 
         spans = self.spans
         with spans.span("engine.step"):
+            chunk, zeros, place = self._assembly(self._x_rows(mb))
             t = self.clock()
             with spans.span("engine.build"):
                 host, x_rows, n_edges, counts = self._prepare(mb)
             with spans.span("engine.pad"):
-                x_pad = self.pad_input(np.asarray(x_in, np.float32), x_rows)
-            nbytes = sum(a.nbytes for a in jax.tree.leaves((host, x_pad)))
+                x_in = np.ascontiguousarray(x_in, np.float32)
+                chunks = self._input_chunks(x_in, chunk)
+            nbytes = sum(a.nbytes for a in jax.tree.leaves((host, chunks)))
+            n_in = len(x_in)
             self.h2d_bytes += nbytes
+            self.input_rows += n_in
+            self.pad_input_rows += x_rows - n_in
             self.tiles += counts["tiles"]
             self.pad_tiles += counts["pad_tiles"]
             self.msg_slots += counts["msg_slots"]
             self.pad_msg_slots += counts["pad_msg_slots"]
             with spans.span("engine.upload", h2d_bytes=nbytes, edges=n_edges,
+                            input_rows=n_in, pad_input_rows=x_rows - n_in,
                             **counts):
-                layers, x_dev = jax.block_until_ready(
-                    jax.device_put((host, x_pad)))
+                layers = jax.device_put(host)
+                x_dev = self._device_input(chunks, chunk, zeros, place)
+                layers, x_dev = jax.block_until_ready((layers, x_dev))
             prep = self.clock() - t
             if self.parity_max_diff is None:
                 with spans.span("engine.parity"):
                     self.check_parity(mb, x_in, _prep=(layers, x_rows))
             args = (self.params, self.opt_state, self.error, x_dev, layers)
-            sig = (x_pad.shape,) + tuple(
+            sig = (x_dev.shape,) + tuple(
                 a.shape for a in jax.tree.leaves(layers))
             if sig not in self._exec:
                 with spans.span("engine.compile"):
@@ -477,11 +577,11 @@ class ComputeEngine:
                 jax.block_until_ready(out)
                 dt = self.clock() - t0
             # the host copies the upload read are held past the timed step
-            # and dropped here, so their free (~0.3 GB at reddit width, the
-            # padded input) is charged to prep_s and not to the compiled step
+            # and dropped here, so their free is charged to prep_s and not
+            # to the compiled step
             with spans.span("engine.free"):
                 t = self.clock()
-                del host, x_pad
+                del host, chunks
                 prep += self.clock() - t
             self.params, self.opt_state, self.error, loss = out
             self.losses.append(float(loss))

@@ -191,15 +191,19 @@ class TestDevicePlumbing:
 
     def test_step_counts_its_upload_exactly(self, monkeypatch):
         """``h2d_bytes`` is the summed ``nbytes`` of the layers' plans
-        ``_prepare`` returns and of ``pad_input``'s padded input; ``tiles``
-        the layers' ``rows`` lengths after padding, ``pad_tiles`` the
-        difference from the plan's lengths before."""
+        ``_prepare`` returns and of the input's chunks as uploaded, the
+        last one's zero rows included; ``input_rows`` the real input rows
+        and ``pad_input_rows`` the rows the device adds up to the bucket;
+        ``tiles`` the layers' ``rows`` lengths after padding,
+        ``pad_tiles`` the difference from the plan's lengths before."""
         import jax
 
         from repro.kernels import segment_mm
+        from repro.train import compute
         from repro.train import gnn_trainer as gt
         from repro.train.compute import ComputeEngine
 
+        monkeypatch.setattr(compute, "INPUT_CHUNK_ROWS", 1024)
         cfg = gt.RunConfig(**dict(_PIN_CFG, n_epochs=1, steps_per_epoch=2))
         graph, _owner, _traces, mbs = gt.build_trace(cfg)
         eng = ComputeEngine(graph, cfg)
@@ -213,22 +217,135 @@ class TestDevicePlumbing:
             return out
 
         monkeypatch.setattr(segment_mm, "block_sparse_plan", spy)
-        want_bytes = want_tiles = 0
+        want_bytes = want_tiles = want_in = want_pad = 0
         for mb in mbs[0]:
             x_in = np.asarray(graph.features[mb.input_nodes], np.float32)
             host, x_rows, _, _ = eng._prepare(mb)
             assert all("blocks" not in layer for layer in host)
-            x_pad = eng.pad_input(x_in, x_rows)
-            want_bytes += sum(a.nbytes for a in
-                              jax.tree.leaves((host, x_pad)))
+            n = len(x_in)
+            chunks = -(-n // 1024)
+            assert chunks * 1024 < x_rows    # fewer rows than the bucket
+            want_bytes += sum(a.nbytes for a in jax.tree.leaves(host))
+            want_bytes += chunks * 1024 * x_in.shape[1] * 4
+            want_in += n
+            want_pad += x_rows - n
             want_tiles += sum(len(layer["rows"]) for layer in host)
             eng.step(mb, x_in)
         # _prepare ran twice per batch: once here, once inside step
         n_before = sum(unpadded) // 2
         assert eng.h2d_bytes == want_bytes
+        assert (eng.input_rows, eng.pad_input_rows) == (want_in, want_pad)
         assert eng.tiles == want_tiles
         assert eng.pad_tiles == want_tiles - n_before
         assert eng.pad_tiles > 0
+
+    @pytest.mark.parametrize("n_rows", [1, 255, 256, 257, 100, 512],
+                             ids=["one", "chunk-1", "chunk", "chunk+1",
+                                  "small-bucket", "full-bucket"])
+    def test_device_input_is_pad_input_bit_for_bit(self, monkeypatch,
+                                                  n_rows):
+        """With 256-row chunks, the input assembled on the device equals
+        ``pad_input``'s bit for bit: one row, a chunk less one, a chunk, a
+        chunk and one, a bucket smaller than a chunk (100 rows of 128) and
+        a full bucket (512). Each follows a tail that leaves the reused
+        host buffer full, whose rows must not reappear."""
+        from repro.train import compute
+        from repro.train import gnn_trainer as gt
+        from repro.train.compute import ComputeEngine
+
+        monkeypatch.setattr(compute, "INPUT_CHUNK_ROWS", 256)
+        cfg = gt.RunConfig(**_PIN_CFG)
+        eng = ComputeEngine(gt.datasets.materialize(cfg.dataset, seed=0),
+                            cfg)
+        rng = np.random.default_rng(n_rows)
+        x_rows = compute._bucket(-(-n_rows // 128)) * 128
+        for n in (x_rows - 1, n_rows):
+            x_in = rng.standard_normal((n, eng.mcfg.d_in)).astype(np.float32)
+            chunk, zeros, place = eng._assembly(x_rows)
+            assert chunk == min(256, x_rows)
+            got = eng._device_input(eng._input_chunks(x_in, chunk), chunk,
+                                    zeros, place)
+            assert got.shape == (x_rows, eng.mcfg.d_in)
+            assert np.array_equal(np.asarray(got),
+                                  eng.pad_input(x_in, x_rows))
+
+    def test_input_assembly_compiles_once_per_bucket(self, monkeypatch):
+        """Over an epoch of batches of 1 to 20 seeds (input buckets of 256
+        to 4,096 rows, chunks of 512), the backend compiles the step once
+        per signature and the input's assembly (two programs) once per
+        (bucket, chunk), whatever the batch's row count."""
+        from repro.graph.sampling import sample_blocks
+        from repro.train import compute
+        from repro.train import gnn_trainer as gt
+        from repro.train.compute import ComputeEngine
+
+        monkeypatch.setattr(compute, "INPUT_CHUNK_ROWS", 512)
+        cfg = gt.RunConfig(**_PIN_CFG)
+        graph = gt.datasets.materialize(cfg.dataset, seed=0)
+        eng = ComputeEngine(graph, cfg)
+        eng.parity_max_diff = float("nan")
+        rng = np.random.default_rng(5)
+        mbs = [sample_blocks(graph, rng.choice(graph.n_nodes, k, False),
+                             list(cfg.fanouts), rng, pad=False)
+               for k in (1, 8, 3, 20, 8, 1, 3, 20)]
+        compiles = []
+
+        def count(event, secs, **kw):
+            if event == "/jax/core/compile/backend_compile_duration":
+                compiles.append(event)
+
+        jax.clear_caches()   # no program compiled by an earlier test
+        jax.monitoring.register_event_duration_secs_listener(count)
+        try:
+            for mb in mbs:
+                eng.step(mb, np.asarray(graph.features[mb.input_nodes],
+                                        np.float32))
+        finally:
+            jax.monitoring.unregister_event_duration_listener(count)
+        buckets = {eng._x_rows(mb) for mb in mbs}
+        keys = {(r, min(512, r)) for r in buckets}
+        assert len({len(mb.input_nodes) for mb in mbs}) == len(mbs)
+        assert len(keys) == 5 and set(eng._assemblers) == keys
+        assert len(compiles) == eng.n_compiles + 2 * len(keys)
+
+    @pytest.mark.parametrize("model", ["sage", "pna"])
+    def test_device_input_trains_as_host_input(self, monkeypatch, model):
+        """Over two steps the engine, which assembles its input on the
+        device from 1,024-row chunks, gives bit for bit the losses and
+        parameters of the same step fed ``pad_input``'s host-padded
+        input, uploaded whole."""
+        from repro.train import compute
+        from repro.train import gnn_trainer as gt
+        from repro.train.compute import ComputeEngine
+
+        class HostPadded(ComputeEngine):
+            def _assembly(self, x_rows):
+                self._rows = x_rows
+                return super()._assembly(x_rows)
+
+            def _input_chunks(self, x_in, chunk):
+                return [self.pad_input(x_in, self._rows)]
+
+            def _device_input(self, chunks, chunk, zeros, place):
+                return jax.device_put(chunks[0])
+
+        monkeypatch.setattr(compute, "INPUT_CHUNK_ROWS", 1024)
+        over = {"pna": dict(model="pna", fanouts=(3,) * 4, batch_size=100)}
+        cfg = gt.RunConfig(**dict(_PIN_CFG, n_epochs=1, steps_per_epoch=2,
+                                  **over.get(model, {})))
+        graph, _owner, _traces, mbs = gt.build_trace(cfg)
+        device, host = ComputeEngine(graph, cfg), HostPadded(graph, cfg)
+        device.parity_max_diff = host.parity_max_diff = float("nan")
+        for mb in mbs[0]:
+            x_in = np.asarray(graph.features[mb.input_nodes], np.float32)
+            assert len(x_in) > 1024 and len(x_in) % 1024
+            device.step(mb, x_in)
+            host.step(mb, x_in)
+        assert device.losses == host.losses
+        for a, b in zip(jax.tree.leaves(device.params),
+                        jax.tree.leaves(host.params)):
+            assert np.array_equal(np.asarray(a), np.asarray(b))
+        assert device.n_compiles == host.n_compiles
 
     def test_device_tiles_train_as_host_tiles(self):
         """Over three steps the engine, which scatters its tiles on the

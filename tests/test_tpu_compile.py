@@ -215,3 +215,21 @@ def test_pna_step_compiles(sds, compiled_kernels):
     # the four forward aggregations run as the kernel; the backward is XLA
     assert exe.as_text().count("tpu_custom_call") == 4
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+@pytest.mark.parametrize("x_rows", [SRC_ROWS_L0, PNA_SRC_ROWS],
+                         ids=["sage", "pna"])
+def test_input_assembly_compiles(sds, x_rows):
+    """The step's input assembly at both cells' input buckets: a chunk of
+    ``INPUT_CHUNK_ROWS`` rows written in place into the donated padded
+    buffer, which the program aliases to its output."""
+    from repro.train import compute
+
+    chunk = compute.INPUT_CHUNK_ROWS
+    assert x_rows % chunk == 0
+    exe = jax.jit(compute._place, donate_argnums=0).lower(
+        sds((x_rows, N_FEAT)), sds((chunk, N_FEAT)), sds((), jnp.int32),
+    ).compile()
+    assert "input_output_alias" in exe.as_text()
+    mem = exe.memory_analysis()
+    assert mem.temp_size_in_bytes < chunk * N_FEAT * 4
